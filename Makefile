@@ -207,7 +207,7 @@ analysis-smoke:
 # (decoded == absorbed + cut + emitted), the `why` verb must
 # reconstruct a stored candidate's full decision chain from only its
 # store record, distilled candidates must be bit-identical with
-# lineage on vs --no-lineage, the writer's self-measured overhead
+# lineage on vs --no-lineage, provenance's cost (its Lineage spans)
 # must stay <1% of drain wall-clock, and a deliberately widened
 # harmonic tolerance must trip the distill_collapse health rule
 lineage-smoke:

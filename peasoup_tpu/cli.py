@@ -247,7 +247,7 @@ def main(argv=None) -> int:
     # happen (a crash still leaves the JSONL trail on disk)
     from .obs.events import configure_event_log
     from .obs.metrics import install_compile_hook
-    from .obs.trace import get_tracer
+    from .obs.trace import get_tracer, span
 
     install_compile_hook()
     os.makedirs(cfg.outdir, exist_ok=True)
@@ -282,7 +282,8 @@ def main(argv=None) -> int:
     t0 = _time.time()
     from .io import read_filterbank
 
-    fil = read_filterbank(cfg.infilename)
+    with span("Read"):
+        fil = read_filterbank(cfg.infilename)
     t_read = _time.time() - t0
 
     if args.verbose:
@@ -296,14 +297,15 @@ def main(argv=None) -> int:
     # The fused mesh program is the default even on one device: a
     # single dispatch + compact transfer beats the per-DM host loop by
     # an order of magnitude on remote-attached accelerators.
-    if args.single_device:
-        search = PulsarSearch(fil, cfg)
-    else:
-        from .parallel.mesh import MeshPulsarSearch
+    with span("Search-Setup"):
+        if args.single_device:
+            search = PulsarSearch(fil, cfg)
+        else:
+            from .parallel.mesh import MeshPulsarSearch
 
-        search = MeshPulsarSearch(
-            fil, cfg, max_devices=args.max_num_threads
-        )
+            search = MeshPulsarSearch(
+                fil, cfg, max_devices=args.max_num_threads
+            )
     if args.profile_dir:
         from .utils import start_trace
 
@@ -317,7 +319,8 @@ def main(argv=None) -> int:
             stop_trace()
     result.timers["reading"] = t_read
     result.timers["total"] = _time.time() - t_total
-    report = write_search_output(result, cfg.outdir)
+    with span("Output"):
+        report = write_search_output(result, cfg.outdir)
     # span trace LAST (it covers the output-writing tail too); on
     # multihost runs every process gathers, process 0 writes the merge
     from .obs.trace import write_merged_trace

@@ -38,13 +38,16 @@ is asserted in tests and ``make lineage-smoke``.  ``clipped`` /
 entered the id'd population (lost before or instead of decode) and are
 aggregate counts by design.
 
-The writer self-accounts its own cost (the ``timeline.overhead()``
-pattern): :func:`overhead` reports marks/seconds/errors so the serve
-ledger can export ``lineage_overhead_s`` and the smoke can gate it
-below 1% of drain wall-clock.  Marking is best-effort and never raises
-— provenance must not kill a multi-hour search.  The stream schema is
-declared in :mod:`.streams` so lint rule PSL013 proves writer/reader
-agreement.
+Provenance is timed by its call sites, not by the writer: every
+contiguous block of provenance work (ids, margins, marks) runs under
+:func:`marking`, one ``Lineage`` span carrying ``marks``, the marks the
+block wrote.  :func:`span_seconds` sums those spans, so the serve
+ledger's ``lineage_overhead_s`` (gated below 1% of drain wall-clock by
+the smoke) covers the id hashing and margin grids computed in the
+callers' arguments too.  :func:`overhead` counts marks and write
+errors.  Marking is best-effort and never raises — provenance must not
+kill a multi-hour search.  The stream schema is declared in
+:mod:`.streams` so lint rule PSL013 proves writer/reader agreement.
 """
 
 from __future__ import annotations
@@ -54,8 +57,10 @@ import json
 import os
 import threading
 import time
+from contextlib import contextmanager
 
 from .metrics import REGISTRY as METRICS
+from .trace import get_tracer, span
 
 LINEAGE_VERSION = 1
 
@@ -74,18 +79,44 @@ AGGREGATE_KINDS = ("clipped", "dropped", "merged", "superseded")
 ANNOTATION_KINDS = ("scored", "fold_cut", "folded", "stored",
                     "quarantined")
 
-# self-accounted writer cost, the timeline.overhead() pattern: the
-# plane that measures the pipeline must measure itself
+#: the span around each contiguous block of provenance work
+LINEAGE_SPAN = "Lineage"
+
 _OV_LOCK = threading.Lock()
-_OVERHEAD = {"marks": 0, "seconds": 0.0, "errors": 0}
+_OVERHEAD = {"marks": 0, "errors": 0}
 
 
 def overhead() -> dict:
-    """Total marks recorded, seconds spent recording them, and write
-    errors, process-wide — exported as ``lineage_overhead_s`` in serve
-    ledger records and gated <1% of drain wall-clock in the smoke."""
+    """Total marks attempted and write errors, process-wide.  Their
+    cost is read from the ``Lineage`` spans (:func:`span_seconds`)."""
     with _OV_LOCK:
         return dict(_OVERHEAD)
+
+
+@contextmanager
+def marking():
+    """One ``Lineage`` span around a block of provenance work, with
+    attribute ``marks``: the change of the ``lineage.marks`` counter
+    across the block.  Opens nothing when no ledger is configured.
+    Wrap a whole block, never one mark: a span polls device memory
+    when it closes."""
+    if _RECORDER is None:
+        yield
+        return
+    m0 = METRICS.counter("lineage.marks")
+    with span(LINEAGE_SPAN) as sp:
+        try:
+            yield
+        finally:
+            sp.set(marks=METRICS.counter("lineage.marks") - m0)
+
+
+def span_seconds(since: int = 0) -> float:
+    """Seconds of the ``Lineage`` spans closed since a
+    :func:`.trace.span_cursor` checkpoint: the whole cost of
+    provenance, ids and margins included."""
+    return sum(r.t_end - r.t_start for r in get_tracer().records()[since:]
+               if r.name == LINEAGE_SPAN)
 
 
 def candidate_uid(run: str, cand) -> str:
@@ -155,7 +186,6 @@ class LineageRecorder:
     def mark(self, kind: str, *, run: str | None = None,
              **fields) -> None:
         """Append one typed decision mark; best-effort, never raises."""
-        t0 = time.perf_counter()
         try:
             rec = {
                 "v": LINEAGE_VERSION,
@@ -187,7 +217,6 @@ class LineageRecorder:
         finally:
             with _OV_LOCK:
                 _OVERHEAD["marks"] += 1
-                _OVERHEAD["seconds"] += time.perf_counter() - t0
 
     def close(self) -> None:
         with self._lock:

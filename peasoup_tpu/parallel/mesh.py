@@ -2022,12 +2022,15 @@ class MeshPulsarSearch(PulsarSearch):
                 ii = int(rows[key])
                 if ii < ndm:
                     all_clipped[ii] = int(counts_l[key].max())
-                    if lineage.enabled():
-                        # the clipped row's partial decode is discarded
-                        # here; the post-loop escalated re-search emits
-                        # fresh ``decoded`` marks for the row
+            if clipped_l and lineage.enabled():
+                # the clipped rows' partial decodes are discarded here;
+                # the post-loop escalated re-search emits fresh
+                # ``decoded`` marks for each row
+                with lineage.marking():
+                    for key in clipped_l:
+                        ii = int(rows[key])
                         grp = groups_l.get(key)
-                        if grp is not None and len(grp[0]):
+                        if ii < ndm and grp is not None and len(grp[0]):
                             lineage.mark(
                                 "superseded", run=self._lineage_run(),
                                 n=int(len(grp[0])),
@@ -2043,7 +2046,8 @@ class MeshPulsarSearch(PulsarSearch):
             # one segmented native call distills every non-clipped row
             # of the chunk (rows with no peaks get an empty group)
             tp = time.time()
-            with span("Distill", metric="distillation", chunk=int(ci)):
+            with span("Distill", metric="distillation", chunk=int(ci),
+                      stage="dm_rows", native=self._segmented_distill()):
                 batch = self._distill_rows_batch(
                     (int(rows[key]), groups_l.get(key),
                      acc_lists[int(rows[key])],
@@ -2579,8 +2583,9 @@ class MeshPulsarSearch(PulsarSearch):
                     len(g[0]) for ii, g in per_dm_groups.items()
                     if ii < ndm)
                 if n_disc:
-                    lineage.mark("superseded", run=self._lineage_run(),
-                                 n=n_disc, stage="redispatch")
+                    with lineage.marking():
+                        lineage.mark("superseded", run=self._lineage_run(),
+                                     n=n_disc, stage="redispatch")
             cap, compact_k = nxt
         rerun = self._rerun_clipped_rows(
             clipped, counts_arr,
@@ -2591,12 +2596,13 @@ class MeshPulsarSearch(PulsarSearch):
             # the escalated host re-search (which emits its own
             # ``decoded`` marks via process_dm_peaks)
             lrun = self._lineage_run()
-            for ii in sorted(rerun):
-                grp = per_dm_groups.get(ii)
-                if grp is not None and len(grp[0]):
-                    lineage.mark("superseded", run=lrun,
-                                 n=int(len(grp[0])),
-                                 stage="clip_rerun", dm_idx=int(ii))
+            with lineage.marking():
+                for ii in sorted(rerun):
+                    grp = per_dm_groups.get(ii)
+                    if grp is not None and len(grp[0]):
+                        lineage.mark("superseded", run=lrun,
+                                     n=int(len(grp[0])),
+                                     stage="clip_rerun", dm_idx=int(ii))
         if cfg.dump_dir:
             # debug buffer dumps work here because the fused path keeps
             # every dedispersed trial HBM-resident (the chunked driver
@@ -2645,7 +2651,8 @@ class MeshPulsarSearch(PulsarSearch):
         timers["searching_device"] = time.time() - t0
         dm_cands = CandidateCollection()
         ckpt_done = {}
-        with span("Distill", metric="distillation", n_dm_trials=ndm):
+        with span("Distill", metric="distillation", n_dm_trials=ndm,
+                  stage="dm_rows", native=self._segmented_distill()):
             batch = self._distill_rows_batch(
                 (ii, per_dm_groups.get(ii), acc_lists[ii],
                  None if jerk_lists is None else jerk_lists[ii])
@@ -2871,15 +2878,16 @@ class MeshPulsarSearch(PulsarSearch):
                 # escalated re-dispatch discards every live beam's
                 # decode; aggregate supersession per beam, attributed
                 # to that beam's run id (see run()'s fused-path note)
-                for b in decoded:
-                    n_disc = sum(
-                        len(g[0])
-                        for ii, g in decoded[b][0].items() if ii < ndm)
-                    if n_disc:
-                        lineage.mark(
-                            "superseded",
-                            run=getattr(configs[b], "lineage_run", ""),
-                            n=n_disc, stage="redispatch")
+                with lineage.marking():
+                    for b in decoded:
+                        n_disc = sum(
+                            len(g[0])
+                            for ii, g in decoded[b][0].items() if ii < ndm)
+                        if n_disc:
+                            lineage.mark(
+                                "superseded",
+                                run=getattr(configs[b], "lineage_run", ""),
+                                n=n_disc, stage="redispatch")
             cap, compact_k = nxt
         # per-beam clipped-row re-searches on that beam's trials
         reruns: dict[int, dict] = {}
@@ -2901,13 +2909,14 @@ class MeshPulsarSearch(PulsarSearch):
                     self._lineage_run_override = ""
                 if reruns[b] and lineage.enabled():
                     lrun_b = getattr(configs[b], "lineage_run", "")
-                    for ii in sorted(reruns[b]):
-                        grp = decoded[b][0].get(ii)
-                        if grp is not None and len(grp[0]):
-                            lineage.mark(
-                                "superseded", run=lrun_b,
-                                n=int(len(grp[0])),
-                                stage="clip_rerun", dm_idx=int(ii))
+                    with lineage.marking():
+                        for ii in sorted(reruns[b]):
+                            grp = decoded[b][0].get(ii)
+                            if grp is not None and len(grp[0]):
+                                lineage.mark(
+                                    "superseded", run=lrun_b,
+                                    n=int(len(grp[0])),
+                                    stage="clip_rerun", dm_idx=int(ii))
             except Exception as exc:
                 beam_fail[b] = exc
                 decoded.pop(b)
@@ -2940,7 +2949,8 @@ class MeshPulsarSearch(PulsarSearch):
         # keep the segments per-beam, so cross-beam absorption is
         # structurally impossible
         with span("Distill", metric="distillation",
-                  n_dm_trials=ndm * max(len(decoded), 1), batch=B):
+                  n_dm_trials=ndm * max(len(decoded), 1), batch=B,
+                  stage="dm_rows", native=self._segmented_distill()):
             distilled = self._distill_rows_batch(
                 (((b, ii), decoded[b][0].get(ii), acc_lists[ii],
                   None if jerk_lists is None else jerk_lists[ii])

@@ -36,6 +36,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..data.candidates import Candidate
+from ..obs import lineage
 
 SPEED_OF_LIGHT = 299792458.0
 
@@ -127,8 +128,10 @@ class BaseDistiller:
         ``on_decision(fundamental, absorbed, margin)`` — the lineage
         callback (ISSUE 19) — fires once per absorbed candidate, for
         its FIRST (highest-SNR) absorber, with the pair's tolerance
-        margin.  Purely observational: candidate output (uniqueness
-        AND assoc lists) is bit-identical with or without it.
+        margin, after the walk and in walk order, all under one
+        ``Lineage`` span.  Purely observational: candidate output
+        (uniqueness AND assoc lists) is bit-identical with or without
+        it.
         """
         size = len(cands)
         # std::sort with snr-greater comparator; stable for determinism
@@ -148,30 +151,30 @@ class BaseDistiller:
             if self.keep_related:
                 for fi, ai in zip(pf, pa):
                     cands[fi].append(cands[ai])
-            if on_decision is not None:
+            pairs = zip(pf, pa)
+        else:
+            unique = np.ones(size, dtype=bool)
+            pairs = []
+            for idx in range(size):
+                if not unique[idx]:
+                    continue
+                counts = self.match_counts(idx)
+                hit = np.nonzero(counts)[0] + idx + 1
+                if self.keep_related:
+                    for ii in hit:
+                        for _ in range(int(counts[ii - idx - 1])):
+                            cands[idx].append(cands[ii])
+                if on_decision is not None:
+                    pairs.extend((idx, ii) for ii in hit if unique[ii])
+                unique[hit] = False
+        if on_decision is not None:
+            with lineage.marking():
                 seen: set[int] = set()  # pairs are in walk order:
-                for fi, ai in zip(pf, pa):  # first absorber wins
+                for fi, ai in pairs:  # first absorber wins
                     if ai not in seen:
                         seen.add(ai)
                         on_decision(cands[fi], cands[ai],
                                     self.pair_margin(int(fi), int(ai)))
-            return [cands[i] for i in range(size) if unique[i]]
-        unique = np.ones(size, dtype=bool)
-        for idx in range(size):
-            if not unique[idx]:
-                continue
-            counts = self.match_counts(idx)
-            hit = np.nonzero(counts)[0] + idx + 1
-            if self.keep_related:
-                for ii in hit:
-                    for _ in range(int(counts[ii - idx - 1])):
-                        cands[idx].append(cands[ii])
-            if on_decision is not None:
-                for ii in hit:
-                    if unique[ii]:  # first absorber wins
-                        on_decision(cands[idx], cands[ii],
-                                    self.pair_margin(int(idx), int(ii)))
-            unique[hit] = False
         return [cands[i] for i in range(size) if unique[i]]
 
 

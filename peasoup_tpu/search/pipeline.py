@@ -706,35 +706,36 @@ class PulsarSearch:
             for j, acc in enumerate(acc_list)
         ]
         if lineage.enabled():
-            lrun = self._lineage_run()
-            # pre-decode loss accounting (aggregates by design: these
-            # peaks never got ids).  clipped = beyond capacity,
-            # dropped = under-delivery sentinels, merged = duplicate
-            # spectrum bins collapsed by identify_unique_peaks
-            cap = capacity or self.config.peak_capacity
-            n_take = n_drop = 0
-            n_clip = 0
-            for j in range(len(acc_list)):
-                for level in range(len(self.bounds)):
-                    cnt = int(counts[j][level])
-                    take = min(cnt, cap)
-                    n_clip += max(cnt - cap, 0)
-                    bi = np.asarray(idxs[j][level][:take])
-                    n_take += take
-                    n_drop += int((bi < 0).sum())
-            n_dec = sum(len(g) for g in groups)
-            if n_clip:
-                lineage.mark("clipped", run=lrun, n=n_clip,
-                             stage="host", dm_idx=int(dm_idx))
-            if n_drop:
-                lineage.mark("dropped", run=lrun, n=n_drop,
-                             stage="host", dm_idx=int(dm_idx))
-            n_merge = n_take - n_drop - n_dec
-            if n_merge:
-                lineage.mark("merged", run=lrun, n=n_merge,
-                             stage="host", dm_idx=int(dm_idx))
-            self._mark_decoded(
-                lrun, dm_idx, [c for g in groups for c in g], "host")
+            with lineage.marking():
+                lrun = self._lineage_run()
+                # pre-decode loss accounting (aggregates by design: these
+                # peaks never got ids).  clipped = beyond capacity,
+                # dropped = under-delivery sentinels, merged = duplicate
+                # spectrum bins collapsed by identify_unique_peaks
+                cap = capacity or self.config.peak_capacity
+                n_take = n_drop = 0
+                n_clip = 0
+                for j in range(len(acc_list)):
+                    for level in range(len(self.bounds)):
+                        cnt = int(counts[j][level])
+                        take = min(cnt, cap)
+                        n_clip += max(cnt - cap, 0)
+                        bi = np.asarray(idxs[j][level][:take])
+                        n_take += take
+                        n_drop += int((bi < 0).sum())
+                n_dec = sum(len(g) for g in groups)
+                if n_clip:
+                    lineage.mark("clipped", run=lrun, n=n_clip,
+                                 stage="host", dm_idx=int(dm_idx))
+                if n_drop:
+                    lineage.mark("dropped", run=lrun, n=n_drop,
+                                 stage="host", dm_idx=int(dm_idx))
+                n_merge = n_take - n_drop - n_dec
+                if n_merge:
+                    lineage.mark("merged", run=lrun, n=n_merge,
+                                 stage="host", dm_idx=int(dm_idx))
+                self._mark_decoded(
+                    lrun, dm_idx, [c for g in groups for c in g], "host")
         return self._distill_accel_groups(groups)
 
     def _distill_dm_row(self, ii, group, acc_list, jerk_list=None,
@@ -758,9 +759,22 @@ class PulsarSearch:
             ])
         if lrun is None:
             lrun = self._lineage_run()
-        self._mark_decoded(lrun, ii, [c for g in groups for c in g],
-                           "mesh")
+        with lineage.marking():
+            self._mark_decoded(lrun, ii, [c for g in groups for c in g],
+                               "mesh")
         return self._distill_accel_groups(groups, lrun=lrun)
+
+    def _segmented_distill(self) -> bool:
+        """Whether :meth:`_distill_rows_batch` runs the segmented native
+        distiller: the native helper is built, and no jerk axis is
+        searched (the native distiller has no jerk predicate, so a
+        jerk-axis search takes the per-row Python path, which chains
+        the JerkDistiller through _distill_accel_groups)."""
+        from ..native import lib as _native
+
+        jp = getattr(self, "jerk_plan", None)
+        return _native is not None and (
+            jp is None or (jp.njerk == 1 and jp.max_abs == 0.0))
 
     def _distill_rows_batch(self, rows, dm_of=None, run_of=None) -> dict:
         """Vectorised per-DM distillation tail for many DM rows at once.
@@ -793,12 +807,7 @@ class PulsarSearch:
             dm_of = lambda k: k
         if run_of is None:
             run_of = lambda k: self._lineage_run()
-        jp = getattr(self, "jerk_plan", None)
-        # the native segmented distiller has no jerk predicate: any
-        # jerk-axis search takes the per-row Python path (which chains
-        # the JerkDistiller through _distill_accel_groups)
-        jerk_free = jp is None or (jp.njerk == 1 and jp.max_abs == 0.0)
-        if _native is None or not jerk_free:
+        if not self._segmented_distill():
             return {
                 ii: self._distill_dm_row(dm_of(ii), grp, acc_list,
                                          jerks, lrun=run_of(ii))
@@ -836,18 +845,19 @@ class PulsarSearch:
         acca = np.concatenate(acca)
         row_keys = [ii for ii, _na in row_meta]
         if want_lineage:
-            # element -> row ordinal, for run/dm attribution of marks
-            rowa = np.repeat(np.asarray(seg_rows, np.int64),
-                             np.diff(bounds_a))
-            for r, key in enumerate(row_keys):
-                sel = np.nonzero(rowa == r)[0]
-                lr = run_of(key)
-                dmi = int(dm_of(key))
-                ids = [lineage.uid_from_fields(
-                    lr, dmi, acca[k], 0.0, nha[k], fa[k])
-                    for k in sel]
-                lineage.mark("decoded", run=lr, ids=ids, n=len(ids),
-                             stage="batch", dm_idx=dmi)
+            with lineage.marking():
+                # element -> row ordinal, for run/dm attribution of marks
+                rowa = np.repeat(np.asarray(seg_rows, np.int64),
+                                 np.diff(bounds_a))
+                for r, key in enumerate(row_keys):
+                    sel = np.nonzero(rowa == r)[0]
+                    lr = run_of(key)
+                    dmi = int(dm_of(key))
+                    ids = [lineage.uid_from_fields(
+                        lr, dmi, acca[k], 0.0, nha[k], fa[k])
+                        for k in sel]
+                    lineage.mark("decoded", run=lr, ids=ids, n=len(ids),
+                                 stage="batch", dm_idx=dmi)
         # pair recording feeds only lineage here (stage-A survivors
         # carry no assoc); uniqueness is independent of the flag, so
         # candidates stay bit-identical with lineage on or off
@@ -858,26 +868,27 @@ class PulsarSearch:
         if want_lineage:
             from .distill import harmonic_margin
 
-            seen_a: set[int] = set()  # pairs are in walk order:
-            for fi, ai in zip(pfa, paa):  # first absorber wins
-                if ai in seen_a:
-                    continue
-                seen_a.add(ai)
-                key = row_keys[int(rowa[ai])]
-                lr = run_of(key)
-                dmi = int(dm_of(key))
-                lineage.mark(
-                    "absorbed", run=lr,
-                    id=lineage.uid_from_fields(
-                        lr, dmi, acca[ai], 0.0, nha[ai], fa[ai]),
-                    absorber=lineage.uid_from_fields(
-                        lr, dmi, acca[fi], 0.0, nha[fi], fa[fi]),
-                    rule="harmonic", stage="dm_row",
-                    margin=round(harmonic_margin(
-                        fa[fi], fa[ai], int(2.0 ** nha[ai]),
-                        cfg.freq_tol, cfg.max_harm), 9),
-                    snr=float(sa[ai]), freq=float(fa[ai]),
-                )
+            with lineage.marking():
+                seen_a: set[int] = set()  # pairs are in walk order:
+                for fi, ai in zip(pfa, paa):  # first absorber wins
+                    if ai in seen_a:
+                        continue
+                    seen_a.add(ai)
+                    key = row_keys[int(rowa[ai])]
+                    lr = run_of(key)
+                    dmi = int(dm_of(key))
+                    lineage.mark(
+                        "absorbed", run=lr,
+                        id=lineage.uid_from_fields(
+                            lr, dmi, acca[ai], 0.0, nha[ai], fa[ai]),
+                        absorber=lineage.uid_from_fields(
+                            lr, dmi, acca[fi], 0.0, nha[fi], fa[fi]),
+                        rule="harmonic", stage="dm_row",
+                        margin=round(harmonic_margin(
+                            fa[fi], fa[ai], int(2.0 ** nha[ai]),
+                            cfg.freq_tol, cfg.max_harm), 9),
+                        snr=float(sa[ai]), freq=float(fa[ai]),
+                    )
         # ---- stage B: acceleration distill per DM segment ------------
         fb, sb, nhb, accb = [], [], [], []
         bounds_b = [0]
@@ -910,29 +921,30 @@ class PulsarSearch:
         if want_lineage:
             from .distill import drift_margin
 
-            tobs_over_c = self.tobs / SPEED_OF_LIGHT
-            rowb = np.repeat(np.arange(len(row_meta), dtype=np.int64),
-                             np.diff(bounds_b))
-            seen_b: set[int] = set()
-            for fi, ai in zip(pf, pa_):
-                if ai in seen_b:
-                    continue
-                seen_b.add(ai)
-                lr = run_of(row_keys[int(rowb[ai])])
-                dmi = int(dmib[ai])
-                lineage.mark(
-                    "absorbed", run=lr,
-                    id=lineage.uid_from_fields(
-                        lr, dmi, accb[ai], 0.0, nhb[ai], fb[ai]),
-                    absorber=lineage.uid_from_fields(
-                        lr, dmi, accb[fi], 0.0, nhb[fi], fb[fi]),
-                    rule="accel", stage="dm_row",
-                    margin=round(drift_margin(
-                        fb[fi], fb[ai],
-                        (accb[fi] - accb[ai]) * tobs_over_c,
-                        cfg.freq_tol), 9),
-                    snr=float(sb[ai]), freq=float(fb[ai]),
-                )
+            with lineage.marking():
+                tobs_over_c = self.tobs / SPEED_OF_LIGHT
+                rowb = np.repeat(np.arange(len(row_meta), dtype=np.int64),
+                                 np.diff(bounds_b))
+                seen_b: set[int] = set()
+                for fi, ai in zip(pf, pa_):
+                    if ai in seen_b:
+                        continue
+                    seen_b.add(ai)
+                    lr = run_of(row_keys[int(rowb[ai])])
+                    dmi = int(dmib[ai])
+                    lineage.mark(
+                        "absorbed", run=lr,
+                        id=lineage.uid_from_fields(
+                            lr, dmi, accb[ai], 0.0, nhb[ai], fb[ai]),
+                        absorber=lineage.uid_from_fields(
+                            lr, dmi, accb[fi], 0.0, nhb[fi], fb[fi]),
+                        rule="accel", stage="dm_row",
+                        margin=round(drift_margin(
+                            fb[fi], fb[ai],
+                            (accb[fi] - accb[ai]) * tobs_over_c,
+                            cfg.freq_tol), 9),
+                        snr=float(sb[ai]), freq=float(fb[ai]),
+                    )
         objs = [
             Candidate(dm=float(self.dm_list[dmib[k]]),
                       dm_idx=int(dmib[k]), acc=float(accb[k]),
@@ -1221,7 +1233,7 @@ class PulsarSearch:
         lrun = (getattr(cfg, "lineage_run", "")
                 or self._lineage_run())
         with span("Distill", metric="distillation",
-                  n_candidates=len(dm_cands.cands)):
+                  n_candidates=len(dm_cands.cands), stage="cross_dm"):
             dm_still = DMDistiller(cfg.freq_tol, True)
             harm_still = HarmonicDistiller(cfg.freq_tol, cfg.max_harm, True,
                                            False)
@@ -1238,13 +1250,14 @@ class PulsarSearch:
         scorer = CandidateScorer(
             hdr.tsamp, hdr.cfreq, hdr.foff, abs(hdr.foff) * self.fil.nchans
         )
-        on_score = None
-        if lineage.enabled():
-            def on_score(c, flags):
-                lineage.mark("scored", run=lrun,
-                             id=lineage.candidate_uid(lrun, c),
-                             flags=flags)
-        scorer.score_all(cands, on_score=on_score)
+        with span("Score", n_candidates=len(cands)):
+            scorer.score_all(cands)
+            if lineage.enabled():
+                with lineage.marking():
+                    for c in cands:
+                        lineage.mark("scored", run=lrun,
+                                     id=lineage.candidate_uid(lrun, c),
+                                     flags=CandidateScorer.flags(c))
 
         import time
 
@@ -1317,25 +1330,25 @@ class PulsarSearch:
             # fold top-N selection is annotated (non-terminal) so a
             # `why` query states whether a candidate was folded or
             # ranked out of the fold budget.
-            for rank, c in enumerate(cands[cfg.limit:],
-                                     start=cfg.limit):
-                lineage.mark("cut", run=lrun,
-                             id=lineage.candidate_uid(lrun, c),
-                             stage="limit", rank=rank,
-                             snr=float(c.snr))
+            with lineage.marking():
+                for rank, c in enumerate(cands[cfg.limit:],
+                                         start=cfg.limit):
+                    lineage.mark("cut", run=lrun,
+                                 id=lineage.candidate_uid(lrun, c),
+                                 stage="limit", rank=rank,
+                                 snr=float(c.snr))
+                for rank, c in enumerate(cands[: cfg.limit]):
+                    cid = lineage.candidate_uid(lrun, c)
+                    if did_fold and (
+                            FOLD_MIN_PERIOD < 1.0 / c.freq
+                            < FOLD_MAX_PERIOD):
+                        lineage.mark(
+                            "folded" if rank < cfg.npdmp else "fold_cut",
+                            run=lrun, id=cid, rank=rank)
+                    lineage.mark("emitted", run=lrun, id=cid, rank=rank,
+                                 snr=float(c.snr), freq=float(c.freq),
+                                 dm_idx=int(c.dm_idx))
         cands = cands[: cfg.limit]
-        if lineage.enabled():
-            for rank, c in enumerate(cands):
-                cid = lineage.candidate_uid(lrun, c)
-                if did_fold and (
-                        FOLD_MIN_PERIOD < 1.0 / c.freq
-                        < FOLD_MAX_PERIOD):
-                    lineage.mark(
-                        "folded" if rank < cfg.npdmp else "fold_cut",
-                        run=lrun, id=cid, rank=rank)
-                lineage.mark("emitted", run=lrun, id=cid, rank=rank,
-                             snr=float(c.snr), freq=float(c.freq),
-                             dm_idx=int(c.dm_idx))
         injection = None
         if cfg.injection_manifest:
             try:

@@ -65,24 +65,20 @@ class CandidateScorer:
         cand.is_adjacent = self._has_adjacency(cand)
         self._delta_dm_ratio(cand)
 
-    def score_all(self, cands: list[Candidate], on_score=None) -> None:
-        """Score every candidate in place.
-
-        ``on_score(cand, flags)`` — the lineage annotation hook
-        (ISSUE 19) — fires after each candidate's verdict with its
-        flag dict, so the provenance ledger records why a `why` query
-        shows the folds/limit treating it the way they did.  Scoring
-        annotates only (`scorer.hpp` never drops candidates): the
-        marks are annotations, not terminal states.
-        """
+    def score_all(self, cands: list[Candidate]) -> None:
+        """Score every candidate in place."""
         for c in cands:
             self.score(c)
-            if on_score is not None:
-                on_score(c, {
-                    "is_physical": bool(c.is_physical),
-                    "is_adjacent": bool(c.is_adjacent),
-                    "ddm_count_ratio": round(
-                        float(c.ddm_count_ratio), 6),
-                    "ddm_snr_ratio": round(
-                        float(c.ddm_snr_ratio), 6),
-                })
+
+    @staticmethod
+    def flags(cand: Candidate) -> dict:
+        """A scored candidate's verdict, as its lineage ``scored`` mark
+        carries it, so the provenance ledger records why a `why` query
+        shows the folds/limit treating it the way they did.  Scoring annotates only (`scorer.hpp` never drops
+        candidates): the marks are annotations, not terminal states."""
+        return {
+            "is_physical": bool(cand.is_physical),
+            "is_adjacent": bool(cand.is_adjacent),
+            "ddm_count_ratio": round(float(cand.ddm_count_ratio), 6),
+            "ddm_snr_ratio": round(float(cand.ddm_snr_ratio), 6),
+        }

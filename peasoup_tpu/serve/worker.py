@@ -462,11 +462,12 @@ class SurveyWorker:
         if not lineage.enabled() or not result.candidates:
             return
         run = str(job.job_id)
-        lineage.mark(
-            "quarantined" if job.canary else "stored", run=run,
-            ids=[lineage.candidate_uid(run, c)
-                 for c in result.candidates],
-            n=len(result.candidates))
+        with lineage.marking():
+            lineage.mark(
+                "quarantined" if job.canary else "stored", run=run,
+                ids=[lineage.candidate_uid(run, c)
+                     for c in result.candidates],
+                n=len(result.candidates))
 
     def _run_batch_jobs(self, jobs: list[JobRecord]) -> int:
         """Run claimed same-bucket jobs through ONE batched dispatch;
@@ -848,7 +849,7 @@ class SurveyWorker:
         # job this drain runs; empty path = the --no-lineage hatch
         lineage_path = os.path.join(self.spool.root, "lineage.jsonl")
         lineage.configure_lineage(lineage_path if self.lineage else "")
-        lov0 = lineage.overhead()  # lineage mark-cost origin
+        lov0 = lineage.overhead()  # lineage mark-count origin
         sampler = self._start_telemetry()
         ov0 = timeline.overhead()  # mark-cost ledger origin
         t0 = time.time()
@@ -931,7 +932,9 @@ class SurveyWorker:
         lov1 = lineage.overhead()
         lg = {
             "marks": lov1["marks"] - lov0["marks"],
-            "overhead_s": round(lov1["seconds"] - lov0["seconds"], 6),
+            # the Lineage spans: marks plus the ids and margins
+            # computed for them
+            "overhead_s": round(lineage.span_seconds(span_c0), 6),
             "errors": lov1["errors"] - lov0["errors"],
         }
         if self.lineage and self._drained_runs:
@@ -1072,7 +1075,7 @@ class SurveyWorker:
                 "canary_missed": int(
                     counters.get("canary.missed", 0)),
                 # candidate provenance (ISSUE 19): the drain's exact
-                # selection funnel + the ledger's self-accounted cost;
+                # selection funnel + the ledger's cost (its spans);
                 # baselines band the fracs and the distill_collapse
                 # health rule fires on departures
                 "lineage_marks": int(lg.get("marks", 0)),

@@ -5,8 +5,9 @@ the lineage ledger on and prove the selection funnel conserves
 EXACTLY — every decoded candidate id reaches exactly one terminal
 state (``decoded == absorbed + cut + emitted``, the
 :func:`peasoup_tpu.obs.lineage.check_conservation` proof), the drain
-summary exports the same funnel, and the writer's self-measured
-overhead stays below 1% of the drain wall-clock.
+summary exports the same funnel, and provenance's cost (the drain's
+``Lineage`` spans: ids, margins and writes) stays below 1% of the
+drain wall-clock.
 
 Phase 2 — the ``why`` verb: starting from ONLY the strongest store
 record (the golden injected-pulse candidate), ``why <candidate-id>``

@@ -18,6 +18,7 @@ from peasoup_tpu.output import (
     OutputFileWriter,
     write_candidate_binary,
 )
+from peasoup_tpu.search import distill
 from peasoup_tpu.serve.health import HealthContext, rule_distill_collapse
 
 
@@ -330,3 +331,144 @@ def test_nassoc_pins_preorder_flatten_and_xml(tmp_path):
         assert int(float(el.findtext("nassoc"))) == c.count_assoc()
         assert el.findtext("candidate_id") == lineage.candidate_uid(
             "r", c)
+
+
+# -------------------------------------------------------------------------
+# the Lineage span: one per block of provenance work, timing it whole
+# -------------------------------------------------------------------------
+
+def _lineage_spans(since):
+    from peasoup_tpu.obs.trace import get_tracer
+
+    return [r for r in get_tracer().records()[since:]
+            if r.name == lineage.LINEAGE_SPAN]
+
+
+def test_marking_spans_a_block_and_counts_its_marks(tmp_path):
+    from peasoup_tpu.obs.trace import span_cursor
+
+    c0 = span_cursor()
+    with lineage.marking():
+        lineage.mark("decoded", ids=["off"])  # lineage off: no span
+    assert _lineage_spans(c0) == []
+    try:
+        lineage.configure_lineage(str(tmp_path / "lineage.jsonl"))
+        with lineage.marking():
+            for i in range(3):
+                lineage.mark("decoded", ids=[f"c{i}"])
+    finally:
+        lineage.configure_lineage("")
+    (sp,) = _lineage_spans(c0)
+    assert sp.attrs["marks"] == 3
+    assert lineage.span_seconds(c0) == pytest.approx(sp.t_end - sp.t_start)
+
+
+def _distill_cands(seed=0, n=60):
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    f0 = rng.uniform(1.0, 50.0, n // 3)
+    # harmonics and near-duplicates of a few fundamentals, and strays
+    freqs = np.concatenate([f0, f0 * rng.choice([0.5, 2.0, 3.0], n // 3),
+                            f0 * (1 + rng.uniform(-5e-5, 5e-5, n // 3))])
+    return [Candidate(dm=float(i % 7), dm_idx=i % 7,
+                      acc=float(rng.choice([-5.0, 0.0, 5.0])),
+                      nh=int(rng.integers(0, 5)), snr=float(s),
+                      freq=float(f))
+            for i, (f, s) in enumerate(zip(freqs,
+                                           rng.uniform(6, 60, len(freqs))))]
+
+
+def _old_walk(still, cands):
+    """The numpy walk as it emitted decisions before: inside the walk,
+    for each fundamental's still-unique hits."""
+    import numpy as np
+
+    cands = sorted(cands, key=lambda c: -c.snr)
+    still.setup(cands)
+    unique = np.ones(len(cands), dtype=bool)
+    out = []
+    for idx in range(len(cands)):
+        if not unique[idx]:
+            continue
+        hit = np.nonzero(still.match_counts(idx))[0] + idx + 1
+        out += [(cands[idx], cands[ii], still.pair_margin(idx, int(ii)))
+                for ii in hit if unique[ii]]
+        unique[hit] = False
+    return out, [c for c, u in zip(cands, unique) if u]
+
+
+@pytest.mark.parametrize("make", [
+    lambda: distill.HarmonicDistiller(1e-4, 16, False),
+    lambda: distill.AccelerationDistiller(42.0, 1e-4, False),
+    lambda: distill.DMDistiller(1e-4, False),
+], ids=["harmonic", "accel", "dm"])
+def test_fallback_distiller_decides_as_before_and_as_native(
+        monkeypatch, make):
+    """The numpy walk now emits its decisions after the walk, under one
+    Lineage span: the same decisions in the same order as when it
+    emitted them inside the walk, the same multiset as the native
+    distiller's, and the same survivors."""
+    import collections
+
+    from peasoup_tpu.obs.trace import span_cursor
+
+    def key(d):
+        f, a, m = d
+        return (f.freq, a.freq, round(m, 9))
+
+    def run(native_on):
+        got = []
+        with monkeypatch.context() as mp:
+            if not native_on:
+                mp.setattr(distill, "_native_lib", lambda: None)
+            out = make().distill(_distill_cands(),
+                                 on_decision=lambda *d: got.append(d))
+        return got, out
+
+    want, want_out = _old_walk(make(), _distill_cands())
+    assert want  # the candidates do absorb one another
+    c0 = span_cursor()
+    got, out = run(False)
+    assert _lineage_spans(c0) == []  # lineage off: no span
+    assert [key(d) for d in got] == [key(d) for d in want]
+    assert [c.freq for c in out] == [c.freq for c in want_out]
+    if distill._native_lib() is not None:
+        nat, nat_out = run(True)
+        assert (collections.Counter(map(key, nat))
+                == collections.Counter(map(key, want)))
+        assert [c.freq for c in nat_out] == [c.freq for c in want_out]
+
+
+def test_drain_lineage_overhead_is_its_lineage_spans(tmp_path):
+    """The drain summary's ``lineage.overhead_s`` is read from the
+    Lineage spans the drain closed: ids and margins included."""
+    import warnings
+
+    from peasoup_tpu.obs.injection import smoke_observation
+    from peasoup_tpu.obs.trace import span_cursor
+    from peasoup_tpu.serve import BackoffPolicy, JobSpool, SurveyWorker
+
+    fil = str(tmp_path / "obs.fil")
+    smoke_observation(fil, nsamps=4096, nchans=16, seed=0)
+    spool = JobSpool(str(tmp_path / "jobs"))
+    spool.submit(fil, {"dm_end": 20.0, "min_snr": 6.0, "npdmp": 2,
+                       "limit": 10})
+    worker = SurveyWorker(
+        spool, single_device=True,
+        backoff=BackoffPolicy(max_attempts=2, base_s=0.0),
+        history_path=str(tmp_path / "history.jsonl"),
+        sleeper=lambda s: None, lineage=True)
+    c0 = span_cursor()
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            summary = worker.drain()
+    finally:
+        lineage.configure_lineage("")
+    spans = _lineage_spans(c0)
+    assert summary["succeeded"] == 1 and spans
+    assert summary["lineage"]["overhead_s"] == round(
+        sum(r.t_end - r.t_start for r in spans), 6)
+    marks = lineage.read_lineage(str(tmp_path / "jobs" / "lineage.jsonl"))
+    assert sum(r.attrs["marks"] for r in spans) == len(marks)
