@@ -75,6 +75,8 @@ CATALOG: dict[str, str] = {
                            "write",
     "lineage.marks": "candidate selection-decision marks written to "
                      "lineage.jsonl",
+    "lineage.writes": "file writes of lineage.jsonl: one per Lineage "
+                      "block with marks, one per mark outside a block",
     # -- peaks / runs -------------------------------------------------------
     "peaks.compact_pallas": "pallas threshold-compaction dispatches",
     "runs.fused_fold_dispatches": "batched fold program dispatches",

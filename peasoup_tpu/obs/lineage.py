@@ -41,13 +41,22 @@ aggregate counts by design.
 Provenance is timed by its call sites, not by the writer: every
 contiguous block of provenance work (ids, margins, marks) runs under
 :func:`marking`, one ``Lineage`` span carrying ``marks``, the marks the
-block wrote.  :func:`span_seconds` sums those spans, so the serve
-ledger's ``lineage_overhead_s`` (gated below 1% of drain wall-clock by
-the smoke) covers the id hashing and margin grids computed in the
-callers' arguments too.  :func:`overhead` counts marks and write
-errors.  Marking is best-effort and never raises — provenance must not
-kill a multi-hour search.  The stream schema is declared in
-:mod:`.streams` so lint rule PSL013 proves writer/reader agreement.
+block wrote, and ``writes``, the file writes it took.  :func:`span_seconds`
+sums those spans, so the serve ledger's ``lineage_overhead_s`` (gated
+below 1% of drain wall-clock by the smoke) covers the id hashing and
+margin grids computed in the callers' arguments too.  :func:`overhead`
+counts marks and write errors.  Marking is best-effort and never raises
+— provenance must not kill a multi-hour search.  The stream schema is
+declared in :mod:`.streams` so lint rule PSL013 proves writer/reader
+agreement.
+
+Marks are written per block: inside :func:`marking` a thread's marks
+are buffered and reach the file as one write, after one size check,
+when its outermost block closes (on an exception too).  A mark made
+outside any block is written at once.  A crash loses at most the marks
+of the blocks still open, and a torn last line is skipped by the
+reader.  Rotation is checked per block, so the live file can pass
+``max_bytes`` by one block before it seals.
 """
 
 from __future__ import annotations
@@ -96,19 +105,25 @@ def overhead() -> dict:
 @contextmanager
 def marking():
     """One ``Lineage`` span around a block of provenance work, with
-    attribute ``marks``: the change of the ``lineage.marks`` counter
-    across the block.  Opens nothing when no ledger is configured.
-    Wrap a whole block, never one mark: a span polls device memory
-    when it closes."""
-    if _RECORDER is None:
+    attributes ``marks`` and ``writes``: the change of the
+    ``lineage.marks`` and ``lineage.writes`` counters across the block.
+    The block's marks are buffered and written when it closes, inside
+    the span (:meth:`LineageRecorder.buffered`).  Opens nothing when no
+    ledger is configured.  Wrap a whole block, never one mark: a span
+    polls device memory when it closes."""
+    rec = _RECORDER
+    if rec is None:
         yield
         return
     m0 = METRICS.counter("lineage.marks")
+    w0 = METRICS.counter("lineage.writes")
     with span(LINEAGE_SPAN) as sp:
         try:
-            yield
+            with rec.buffered():
+                yield
         finally:
-            sp.set(marks=METRICS.counter("lineage.marks") - m0)
+            sp.set(marks=METRICS.counter("lineage.marks") - m0,
+                   writes=METRICS.counter("lineage.writes") - w0)
 
 
 def span_seconds(since: int = 0) -> float:
@@ -149,8 +164,10 @@ def uid_from_fields(run: str, dm_idx, acc, jerk, nh, freq) -> str:
 class LineageRecorder:
     """Append-only JSONL mark sink with ``.1`` rotation.
 
-    The handle opens lazily and is line-buffered; an I/O failure
-    disables persistence for the rest of the run (counted in
+    The handle opens lazily and is line-buffered.  Inside
+    :meth:`buffered` a thread's marks are held and written together;
+    elsewhere each mark is written at once.  An I/O failure disables
+    persistence for the rest of the run (each mark it loses counted in
     ``lineage.mark_errors``, never an exception)."""
 
     def __init__(self, path: str, run: str = "", *,
@@ -161,6 +178,7 @@ class LineageRecorder:
         self._lock = threading.Lock()
         self._file = None
         self._io_failed = False
+        self._local = threading.local()  # .lines: this thread's block
 
     def _maybe_rotate(self) -> None:
         # caller holds the lock; errors are swallowed (a stat race
@@ -183,6 +201,51 @@ class LineageRecorder:
         except OSError:
             pass
 
+    def _lost(self, n: int) -> None:
+        with self._lock:
+            self._io_failed = True
+        METRICS.inc("lineage.mark_errors", n)
+        with _OV_LOCK:
+            _OVERHEAD["errors"] += n
+
+    def _write(self, text: str, n: int) -> None:
+        """Write ``n`` marks' lines as one write after one size check;
+        a failure latches and counts the ``n`` marks lost.  A latch set
+        before the call drops them uncounted, as it does a mark made
+        after it."""
+        try:
+            with self._lock:
+                if self._io_failed:
+                    return
+                self._maybe_rotate()
+                if self._file is None:
+                    d = os.path.dirname(self.path)
+                    if d:
+                        os.makedirs(d, exist_ok=True)
+                    self._file = open(self.path, "a", buffering=1)
+                self._file.write(text)
+            METRICS.inc("lineage.writes")
+            METRICS.inc("lineage.marks", n)
+        except OSError:
+            self._lost(n)
+
+    @contextmanager
+    def buffered(self):
+        """Hold this thread's marks until the outermost block closes,
+        then write them as one (an exception in the block still writes
+        them).  Other threads' marks are never held here."""
+        loc = self._local
+        if getattr(loc, "lines", None) is not None:
+            yield  # nested: the outermost block writes
+            return
+        loc.lines = []
+        try:
+            yield
+        finally:
+            lines, loc.lines = loc.lines, None
+            if lines:
+                self._write("".join(lines), len(lines))
+
     def mark(self, kind: str, *, run: str | None = None,
              **fields) -> None:
         """Append one typed decision mark; best-effort, never raises."""
@@ -197,23 +260,14 @@ class LineageRecorder:
                 if v is not None:
                     rec[k] = v
             line = json.dumps(rec) + "\n"
-            with self._lock:
-                if self._io_failed:
-                    return
-                self._maybe_rotate()
-                if self._file is None:
-                    d = os.path.dirname(self.path)
-                    if d:
-                        os.makedirs(d, exist_ok=True)
-                    self._file = open(self.path, "a", buffering=1)
-                self._file.write(line)
-            METRICS.inc("lineage.marks")
-        except (OSError, TypeError, ValueError):
-            with self._lock:
-                self._io_failed = True
-            METRICS.inc("lineage.mark_errors")
-            with _OV_LOCK:
-                _OVERHEAD["errors"] += 1
+        except (TypeError, ValueError):
+            self._lost(1)
+        else:
+            lines = getattr(self._local, "lines", None)
+            if lines is None:
+                self._write(line, 1)
+            elif not self._io_failed:
+                lines.append(line)
         finally:
             with _OV_LOCK:
                 _OVERHEAD["marks"] += 1

@@ -4,6 +4,8 @@ baselines — plus the assoc-count round-trip pinning ``<nassoc>`` to
 the binary writer's pre-order flatten."""
 
 import json
+import os
+import threading
 import time
 import xml.etree.ElementTree as ET
 
@@ -91,6 +93,34 @@ def test_recorder_rotates_and_reader_spans_generations(tmp_path):
     want = [f"c{i}" for i in range(40)]
     assert 0 < len(got) < 40
     assert got == want[-len(got):]
+
+
+def _block_lines(path):
+    with open(path) as f:
+        return [json.loads(line)["ids"][0] for line in f]
+
+
+def test_recorder_rotates_whole_blocks(tmp_path, monkeypatch):
+    """Rotation is checked once per block: a block never splits across
+    generations, and the live file passes ``max_bytes`` by at most the
+    one block that crossed it."""
+    calls = _count_io(monkeypatch)
+    path = str(tmp_path / "lineage.jsonl")
+    rec = lineage.LineageRecorder(path, run="r", max_bytes=256)
+    for b in range(12):
+        with rec.buffered():
+            for i in range(5):
+                rec.mark("decoded", ids=[f"c{b}.{i}"])
+        assert calls == {"getsize": b + 1, "writes": b + 1}
+        live = os.stat(path).st_size
+        assert len(_block_lines(path)) % 5 == 0
+        assert live < 256 + live // len(_block_lines(path)) * 5
+    rec.close()
+    sealed = _block_lines(path + ".1")
+    assert sealed and len(sealed) % 5 == 0
+    got = [m["ids"][0] for m in lineage.read_lineage(path)]
+    want = [f"c{b}.{i}" for b in range(12) for i in range(5)]
+    assert 0 < len(got) < len(want) and got == want[-len(got):]
 
 
 def test_recorder_io_failure_latches_never_raises(tmp_path):
@@ -472,3 +502,233 @@ def test_drain_lineage_overhead_is_its_lineage_spans(tmp_path):
         sum(r.t_end - r.t_start for r in spans), 6)
     marks = lineage.read_lineage(str(tmp_path / "jobs" / "lineage.jsonl"))
     assert sum(r.attrs["marks"] for r in spans) == len(marks)
+
+
+# -------------------------------------------------------------------------
+# the writer: one write and one size check per Lineage block
+# -------------------------------------------------------------------------
+
+class _CountingFile:
+    """A file handle that counts its ``write`` calls (and can fail
+    them)."""
+
+    def __init__(self, f, calls, fail):
+        self._f, self._calls, self._fail = f, calls, fail
+
+    def write(self, text):
+        self._calls["writes"] += 1
+        if self._fail:
+            raise OSError("disk full")
+        return self._f.write(text)
+
+    def __getattr__(self, name):
+        return getattr(self._f, name)
+
+
+def _count_io(monkeypatch, fail_write=False):
+    """Count the recorder's ``os.path.getsize`` calls and its writes to
+    the files it opens for appending."""
+    calls = {"getsize": 0, "writes": 0}
+    getsize = os.path.getsize
+
+    def counting_getsize(p):
+        calls["getsize"] += 1
+        return getsize(p)
+
+    def counting_open(path, mode="r", *a, **k):
+        f = open(path, mode, *a, **k)
+        return _CountingFile(f, calls, fail_write) if "a" in mode else f
+
+    monkeypatch.setattr(os.path, "getsize", counting_getsize)
+    monkeypatch.setattr(lineage, "open", counting_open, raising=False)
+    return calls
+
+
+def _some_marks(n):
+    """``n`` marks of mixed kinds, with None fields and a run override."""
+    kinds = [("decoded", {"ids": ["a", "b"], "n": 2, "stage": None}),
+             ("absorbed", {"id": "a", "absorber": "b", "rule": "harmonic",
+                           "margin": 1.5e-5, "run": "other"}),
+             ("emitted", {"id": "b", "rank": 0, "snr": None})]
+    return [(kinds[i % 3][0], dict(kinds[i % 3][1], seq=i))
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("n", [1, 5, 300])
+@pytest.mark.parametrize("buffered", [True, False],
+                         ids=["block", "direct"])
+def test_block_makes_one_write_and_one_size_check(tmp_path, monkeypatch,
+                                                  n, buffered):
+    calls = _count_io(monkeypatch)
+    path = str(tmp_path / "lineage.jsonl")
+    rec = lineage.LineageRecorder(path, run="r")
+    if buffered:
+        with rec.buffered():
+            for kind, fields in _some_marks(n):
+                rec.mark(kind, **fields)
+            assert calls == {"getsize": 0, "writes": 0}
+            assert not os.path.exists(path)  # nothing before the exit
+    else:
+        for kind, fields in _some_marks(n):
+            rec.mark(kind, **fields)
+    rec.close()
+    want = 1 if buffered else n
+    assert calls == {"getsize": want, "writes": want}
+    assert len(lineage.read_lineage(path)) == n
+
+
+def _masked(path):
+    """The file's marks, ``ts`` masked, fields in written order."""
+    with open(path) as f:
+        recs = [json.loads(line) for line in f]
+    assert all(isinstance(r.pop("ts"), float) for r in recs)
+    return [list(r.items()) for r in recs]
+
+
+def test_block_writes_the_file_the_marks_write_one_by_one(tmp_path):
+    marks = _some_marks(30)
+    paths = {}
+    for how in ("block", "direct"):
+        paths[how] = str(tmp_path / how / "lineage.jsonl")
+        rec = lineage.LineageRecorder(paths[how], run="r")
+        if how == "block":
+            with rec.buffered():
+                for kind, fields in marks:
+                    rec.mark(kind, **fields)
+        else:
+            for kind, fields in marks:
+                rec.mark(kind, **fields)
+        rec.close()
+    assert _masked(paths["block"]) == _masked(paths["direct"])
+    assert len(_masked(paths["block"])) == 30
+
+
+def test_nested_blocks_write_once_at_the_outermost_exit(tmp_path,
+                                                       monkeypatch):
+    from peasoup_tpu.obs.trace import span_cursor
+
+    calls = _count_io(monkeypatch)
+    path = str(tmp_path / "lineage.jsonl")
+    c0 = span_cursor()
+    try:
+        lineage.configure_lineage(path, run="r")
+        with lineage.marking():
+            lineage.mark("decoded", ids=["a"])
+            with lineage.marking():
+                lineage.mark("decoded", ids=["b"])
+            assert calls["writes"] == 0
+            lineage.mark("decoded", ids=["c"])
+    finally:
+        lineage.configure_lineage("")
+    assert calls == {"getsize": 1, "writes": 1}
+    assert [m["ids"][0] for m in lineage.read_lineage(path)] == ["a", "b",
+                                                                "c"]
+    inner, outer = _lineage_spans(c0)  # records close inner first
+    assert (inner.attrs["marks"], inner.attrs["writes"]) == (0, 0)
+    assert (outer.attrs["marks"], outer.attrs["writes"]) == (3, 1)
+
+
+def test_exception_in_a_block_still_writes_its_marks(tmp_path):
+    path = str(tmp_path / "lineage.jsonl")
+    rec = lineage.LineageRecorder(path, run="r")
+    with pytest.raises(RuntimeError, match="boom"):
+        with rec.buffered():
+            rec.mark("decoded", ids=["a"])
+            rec.mark("cut", id="a", stage="limit")
+            raise RuntimeError("boom")
+    rec.close()
+    assert [m["kind"] for m in lineage.read_lineage(path)] == ["decoded",
+                                                               "cut"]
+
+
+def test_threads_blocks_land_whole_and_in_order(tmp_path):
+    """More threads than cores, each in its own block, all blocks open
+    at once and switching often: every mark lands, each thread's marks
+    in order and contiguous, one write a block."""
+    import sys
+
+    from peasoup_tpu.obs.metrics import REGISTRY
+
+    path = str(tmp_path / "lineage.jsonl")
+    rec = lineage.LineageRecorder(path, run="r")
+    names = [f"t{k:02d}." for k in range((os.cpu_count() or 1) + 4)]
+    all_open = threading.Barrier(len(names))
+    marks0 = REGISTRY.counter("lineage.marks")
+    writes0 = REGISTRY.counter("lineage.writes")
+
+    def work(t):
+        with rec.buffered():
+            rec.mark("decoded", ids=[f"{t}0"])
+            all_open.wait(timeout=30)  # every block overlaps every other
+            for i in range(1, 200):
+                rec.mark("decoded", ids=[f"{t}{i}"])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=work, args=(t,)) for t in names]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in ts)
+    rec.close()
+    got = [m["ids"][0] for m in lineage.read_lineage(path)]
+    assert len(got) == 200 * len(names)
+    for t in names:
+        at = [k for k, g in enumerate(got) if g.startswith(t)]
+        assert at == list(range(at[0], at[0] + 200))  # contiguous
+        assert [got[k] for k in at] == [f"{t}{i}" for i in range(200)]
+    assert REGISTRY.counter("lineage.marks") - marks0 == len(got)
+    assert REGISTRY.counter("lineage.writes") - writes0 == len(names)
+
+
+@pytest.mark.parametrize("fail", ["open", "write"])
+def test_failed_block_write_latches_counts_and_never_raises(
+        tmp_path, monkeypatch, fail):
+    from peasoup_tpu.obs.metrics import REGISTRY
+
+    if fail == "open":
+        blocker = tmp_path / "dir"
+        blocker.write_text("a file where the ledger dir should be")
+        path = str(blocker / "lineage.jsonl")
+    else:
+        _count_io(monkeypatch, fail_write=True)
+        path = str(tmp_path / "lineage.jsonl")
+    rec = lineage.LineageRecorder(path, run="r")
+    before = lineage.overhead()
+    marks0 = REGISTRY.counter("lineage.marks")
+    errors0 = REGISTRY.counter("lineage.mark_errors")
+    with rec.buffered():  # must not raise
+        for i in range(4):
+            rec.mark("decoded", ids=[f"c{i}"])
+    after = lineage.overhead()
+    assert after["errors"] - before["errors"] == 4
+    assert after["marks"] - before["marks"] == 4
+    assert REGISTRY.counter("lineage.mark_errors") - errors0 == 4
+    assert REGISTRY.counter("lineage.marks") == marks0
+    with rec.buffered():  # latched: dropped, not retried
+        rec.mark("decoded", ids=["late"])
+    assert lineage.overhead()["errors"] == after["errors"]
+    rec.close()
+
+
+@pytest.mark.parametrize("n,writes", [(0, 0), (1, 1), (7, 1)])
+def test_marking_span_counts_the_blocks_marks_and_writes(tmp_path, n,
+                                                         writes):
+    from peasoup_tpu.obs.trace import span_cursor
+
+    c0 = span_cursor()
+    path = str(tmp_path / "lineage.jsonl")
+    try:
+        lineage.configure_lineage(path, run="r")
+        with lineage.marking():
+            for kind, fields in _some_marks(n):
+                lineage.mark(kind, **fields)
+    finally:
+        lineage.configure_lineage("")
+    (sp,) = _lineage_spans(c0)
+    assert (sp.attrs["marks"], sp.attrs["writes"]) == (n, writes)
+    assert len(lineage.read_lineage(path)) == n
