@@ -32,12 +32,15 @@ def _merge(per_beam: list[dict]) -> dict:
         n[k] for n in per_beam) for k in KEYS}
 
 
-def readings(spec: dict, seed: int, workdir: str, chips: int = 1) -> dict:
+def readings(spec: dict, seed: int, workdir: str, devices=None) -> dict:
+    """One seed's two sets of numbers; the CLI runs on as many chips as
+    ``devices`` holds (one if not given), the references on them."""
     import jax.numpy as jnp
 
     from perfbench import check, run
 
     prep = run.prepare(spec, seed, workdir)
+    chips = len(devices) if devices else 1
     obs = [run.observe(b["fil"], prep["args"] + ["-t", str(chips)],
                        os.path.join(workdir, f"obs{k}"))
            for k, b in enumerate(prep["beams"])]
@@ -48,9 +51,10 @@ def readings(spec: dict, seed: int, workdir: str, chips: int = 1) -> dict:
     program, control, sizes = [], [], []
     for b, o in zip(prep["beams"], obs):
         table = check.read_candidates(os.path.join(o["outdir"], "overview.xml"))
-        ref, ref_table = run.reference(spec, prep, b["payload"])
+        ref, ref_table = run.reference(spec, prep, b["payload"],
+                                       devices=devices)
         _, ctl_table = run.reference(spec, prep, b["payload"], jnp.bfloat16,
-                                     spec["config"]["npdmp"])
+                                     spec["config"]["npdmp"], devices)
         sizes.append([len(table), len(ref_table), len(ctl_table)])
         program.append(run.correctness([table], ref, ref_table, prep))
         control.append(run.correctness([ctl_table], ref, ref_table, prep))
@@ -69,13 +73,14 @@ def main(argv=None, *, require_tpu: bool = True) -> int:
     a = p.parse_args(argv)
     spec = run.load_cell(a.workload, run.ROOT)
     chips = int(spec["cell"]["chips"])
-    if run.start(chips, require_tpu, run.ROOT) is None:
+    devices = run.start(chips, require_tpu, run.ROOT)
+    if devices is None:
         return 3
     workdir = os.path.join(run.ROOT, ".cache", "perfbench",
                            a.workload + ".control")
     for s in a.seeds.split(","):
         t0 = time.perf_counter()
-        out = readings(spec, int(s) % (1 << 64), workdir, chips)
+        out = readings(spec, int(s) % (1 << 64), workdir, devices)
         out["seconds"] = time.perf_counter() - t0
         print(json.dumps(out), flush=True)
     return 0

@@ -2,8 +2,10 @@
 write, worked out from the observation alone.
 
 Straightforward ``jax.numpy`` at float32 (matmuls at ``highest``), no
-kernels, chunking or batching, and nothing imported from the program.
-It follows peasoup's published semantics stage by stage:
+kernels or chunking, and nothing imported from the program.  Each DM
+row's work runs on one device, its acceleration trials in blocks; only
+a block's above-threshold bins come back to the host, which merges and
+distils them.  It follows peasoup's published semantics stage by stage:
 
 * dedispersion: ``tim[t] = sum_c x[t + delay_c, c]`` with dedisp's
   integer delays (its float32 table, ``synth.delay_table``), the
@@ -16,7 +18,8 @@ It follows peasoup's published semantics stage by stage:
   irfft;
 * acceleration: the search resamples with ``rint(i + i*af*(i - n))``,
   the fold with ``rint(i + af*((i - n/2)^2 - (n/2)^2))``,
-  ``af = a*tsamp/(2c)``, indices worked out in float64 on the host;
+  ``af = a*tsamp/(2c)``, indices worked out in float64 on the host
+  (the search's as shifts ``index - i``, selected on the device);
 * spectrum: rfft, interbinning ``sqrt(max(|X_k|^2, |X_k - X_{k-1}|^2/2))``,
   normalised by the whitening statistics; harmonic level ``k`` adds the
   spectrum at ``(i*m + 2^(k-1)) >> k`` for the odd ``m < 2^k`` and
@@ -142,28 +145,96 @@ def whiten(tim, zap, *, bin_width, dtype=jnp.float32):
     return _q(jnp.fft.irfft(fs, n=n), dtype), mean, std
 
 
-@partial(jax.jit, static_argnames=("dtype",))
-def spectrum(tim_w, idx, mean, std, *, dtype=jnp.float32):
-    """Normalised interbinned spectrum of the resampled series."""
-    fs = _q(jnp.fft.rfft(tim_w[idx]), dtype)
-    return _q((_q(_interbin(fs), dtype) - mean) / std, dtype)
-
-
-def harmonic_levels(spec: np.ndarray, nh: int, dtype=np.float32) -> list:
-    """Levels ``0..nh`` of the incremental harmonic sum: level ``k`` adds
-    the spectrum at ``(i*m + 2^(k-1)) >> k`` for the odd ``m < 2^k`` to
-    level ``k - 1``'s unscaled sum, and is scaled by ``2^(-k/2)``."""
-    size = spec.shape[0]
-    spec = spec.astype(dtype)
-    i = np.arange(size, dtype=np.int64)
-    val = spec.copy()
-    out = [spec]
-    for k in range(1, nh + 1):
-        for m in range(1, 1 << k, 2):
-            val = (val + spec[np.minimum((i * m + (1 << (k - 1))) >> k,
-                                         size - 1)]).astype(dtype)
-        out.append((val * dtype(2.0 ** (-k / 2))).astype(dtype))
+def resample(tim, shift, width: int):
+    """``tim[i + shift[i]]`` for ``|shift| <= width``, by selecting among
+    the ``2 width + 1`` shifted copies (exact, and no gather)."""
+    n = tim.shape[0]
+    pad = jnp.pad(tim, width)
+    out = jnp.zeros_like(tim)
+    for s in range(-width, width + 1):
+        out = jnp.where(shift == s, pad[width + s: width + s + n], out)
     return out
+
+
+LANES = 128
+
+
+def _harmonic_read(k: int, m: int):
+    """Level ``k``'s read of numerator ``m`` in the (rows, 128) view.
+
+    Output ``i = 128 R + l`` reads ``(i m + 2^(k-1)) >> k``.  With
+    ``P = 2^k``, ``S = 128 m / P`` (whole for ``k <= 7``) and
+    ``R = P t + r`` that is ``128 (m t + q_r) + b_r + c_l``, where
+    ``q_r, b_r = divmod(S r, 128)``, ``c_l = (l m + 2^(k-1)) >> k`` and
+    ``b_r + c_l < 256``.  So output rows ``P t + r`` are rows
+    ``m t + q_r`` of the view of adjacent row pairs (256 lanes), with
+    the fixed lane selection ``sel[r]``.  Returns ``q`` and ``sel``."""
+    p, half = 1 << k, 1 << (k - 1)
+    step = LANES * m // p
+    lanes = np.arange(LANES)
+    c = (lanes * m + half) >> k
+    sel = np.zeros((p, 2 * LANES, LANES), np.float32)
+    for r in range(p):
+        sel[r, step * r % LANES + c, lanes] = 1.0
+    return [step * r // LANES for r in range(p)], sel
+
+
+def harmonic_levels(spec, nh: int, dtype=jnp.float32):
+    """Levels ``0..nh`` of the incremental harmonic sum, (nh + 1, size)
+    in ``dtype``: level ``k`` adds the spectrum at
+    ``min((i*m + 2^(k-1)) >> k, size - 1)`` for the odd ``m < 2^k``, in
+    that order, to level ``k - 1``'s unscaled sum, and is scaled by
+    ``2^(-k/2)``.  Each stretched read is a strided row slice and a 0/1
+    lane selection (``_harmonic_read``; an einsum at ``highest``, which is
+    exact), not a gather; the spectrum's last value stands past its
+    end, as the clip says."""
+    size = spec.shape[0]
+    pmax = 1 << nh
+    rows = -(-size // (LANES * pmax)) * pmax
+    # reads reach row m t + q_r + 1 < rows + pmax + 1
+    x = jnp.pad(spec.astype(dtype), (0, (rows + pmax + 2) * LANES - size),
+                mode="edge")
+    tiles = x.reshape(-1, LANES)
+    pairs = jnp.concatenate([tiles[:-1], tiles[1:]], axis=1).astype(jnp.float32)
+    val = tiles[:rows]
+    out = [x[:size]]
+    for k in range(1, nh + 1):
+        p = 1 << k
+        for m in range(1, p, 2):
+            q, sel = _harmonic_read(k, m)
+            src = jnp.stack([pairs[q[r]::m][: rows // p] for r in range(p)])
+            got = jnp.einsum("ptc,pcl->tpl", src, sel,
+                             precision=jax.lax.Precision.HIGHEST)
+            val = (val + got.reshape(rows, LANES).astype(dtype)).astype(dtype)
+        scale = jnp.asarray(2.0 ** (-k / 2), dtype)
+        out.append((val.reshape(-1)[:size] * scale).astype(dtype))
+    return jnp.stack(out)
+
+
+@partial(jax.jit, static_argnames=("nh", "width", "capacity", "dtype"))
+def peaks(tim_w, shifts, mean, std, bounds, thresh, *, nh, width, capacity,
+          dtype=jnp.float32):
+    """The above-threshold bins of a block of one DM row's acceleration
+    trials.  Per trial (a row of ``shifts``): the normalised interbinned
+    spectrum of the resampled series, its harmonic levels, and the bins
+    of each level ``k`` in ``bounds[k]`` above ``thresh``.  Returns how
+    many there are, and the first ``capacity`` of them in (trial, level,
+    bin) order: their flat positions and S/N."""
+
+    def trial(shift):
+        fs = _q(jnp.fft.rfft(resample(tim_w, shift, width)), dtype)
+        spec = _q((_q(_interbin(fs), dtype) - mean) / std, dtype)
+        return harmonic_levels(spec, nh, dtype)
+
+    lv = jax.vmap(trial)(shifts)
+    b = jnp.arange(lv.shape[-1])
+    hit = ((lv > thresh.astype(dtype)) & (b >= bounds[:, :1])
+           & (b < bounds[:, 1:])).reshape(-1)
+    csum = jnp.cumsum(hit, dtype=jnp.int32)
+    # the j-th hit is where the running count first reaches j
+    pos = jnp.searchsorted(csum, jnp.arange(1, capacity + 1, dtype=jnp.int32))
+    pos = jnp.minimum(pos, hit.shape[0] - 1)
+    return csum[-1], pos, lv.reshape(-1)[pos].astype(jnp.float32)
 
 
 def accel_list(cfg: dict, obs: dict, dm: float, size: int) -> np.ndarray:
@@ -348,19 +419,31 @@ def folded_snr(tim_w, accel, period, tsamp, dtype=jnp.float32) -> float:
     return _on_off_snr(np.asarray(prof), best % NBINS - width // 2, width)
 
 
+#: resampled samples a block of trials holds at most
+BLOCK_SAMPLES = 1 << 24
+#: hits a block returns before it is redone at a larger capacity
+CAPACITY = 1 << 14
+
+
 class Reference:
-    """The reference over one observation, on the device.
+    """The reference over one observation, on the devices.
 
     ``payload`` is the observation's packed samples, (nsamps, bytes per
     sample); ``max_dm`` the top of the DM grid, which sets the trial
     length ``nsamps - max_delay``; ``birdies`` the (frequency, width)
     pairs zapped.  A trial shorter than the FFT is padded with its
     mean; the fold takes the power of two strictly below the trial
-    length.  Whitened series are kept per (DM, length), so each is
-    worked out once."""
+    length.  Whitened series are kept per (DM, length, device), so each
+    is worked out once.
+
+    ``devices`` (the first device if not given) each hold a copy of the
+    payload, and DM row ``i`` is searched on device ``i mod n``; folds
+    run on the first.  ``capacity`` is how many hits a block of trials
+    returns before it is redone at a larger capacity."""
 
     def __init__(self, obs: dict, payload: np.ndarray, max_dm: float,
-                 dtype=jnp.float32, birdies=()):
+                 dtype=jnp.float32, birdies=(), devices=None,
+                 capacity: int = CAPACITY):
         self.obs, self.dtype = obs, dtype
         self.npdt = np.dtype(dtype).type  # numpy's (ml_dtypes') scalar type
         self.size = _prev_pow2(obs["nsamps"])
@@ -369,9 +452,12 @@ class Reference:
         max_delay = int(np.float32(max_dm) * self.table[-1] + 0.5)
         self.out = obs["nsamps"] - max_delay
         self.fold_n = _prev_pow2(self.out)
-        self.rows = jnp.asarray(payload).T
+        self.devices = list(devices or jax.devices()[:1])
+        self.rows = {d: jax.device_put(payload, d).T for d in self.devices}
         self.birdies = np.asarray(birdies, np.float32).reshape(-1, 2)
+        self.capacity = capacity
         self._white, self._folded = {}, {}
+        self._resample, self._shifts = {}, {}
 
     def zap_mask(self, n: int) -> np.ndarray:
         """Bins ``[floor((f - w)/bw), ceil((f + w)/bw))`` of each birdie,
@@ -385,59 +471,105 @@ class Reference:
             mask[max(lo, 0):hi] = True
         return mask
 
-    def whitened(self, dm: float, n: int, zap: bool = True):
+    def whitened(self, dm: float, n: int, zap: bool = True, device=None):
         """Whitened first ``n`` samples of the trial (padded to ``n``
-        with its mean where the trial is shorter); the fold's whitening
-        zaps nothing (``folder.hpp:382-389``)."""
-        key = (np.float32(dm), n, zap)
+        with its mean where the trial is shorter), on ``device`` (the
+        first if not given); the fold's whitening zaps nothing
+        (``folder.hpp:382-389``)."""
+        device = device or self.devices[0]
+        key = (np.float32(dm), n, zap, device)
         if key not in self._white:
             delays = np.floor(key[0] * self.table + np.float32(0.5)).astype(np.int32)
-            tim = dedisperse(self.rows, jnp.asarray(delays), size=min(n, self.out),
-                             nbits=self.obs["nbits"], dtype=self.dtype)
+            tim = dedisperse(self.rows[device], jax.device_put(delays, device),
+                             size=min(n, self.out), nbits=self.obs["nbits"],
+                             dtype=self.dtype)
             if n > self.out:
                 tim = jnp.concatenate(
                     [tim, jnp.full(n - self.out, jnp.mean(tim), tim.dtype)])
             mask = self.zap_mask(n) if zap else np.zeros(n // 2 + 1, bool)
-            self._white[key] = whiten(tim, jnp.asarray(mask),
+            self._white[key] = whiten(tim, jax.device_put(mask, device),
                                       bin_width=1.0 / (n * self.obs["tsamp"]),
                                       dtype=self.dtype)
         return self._white[key]
+
+    def shifts(self, accs, device):
+        """The search's resampling of the trials ``accs`` as shifts
+        ``index - i``, (len(accs), size) on ``device``, and the widest."""
+        key = tuple(np.float32(a) for a in accs)
+        if key not in self._resample:
+            i = np.arange(self.size)
+            d = np.stack([search_index(self.size, float(a), self.obs["tsamp"])
+                          - i for a in accs])
+            width = int(np.abs(d).max())
+            self._resample[key] = (d.astype(np.min_scalar_type(-width - 1)),
+                                   width)
+        d, width = self._resample[key]
+        if (key, device) not in self._shifts:
+            self._shifts[key, device] = jax.device_put(d, device)
+        return self._shifts[key, device], width
 
     def search(self, dms, cfg: dict) -> list[dict]:
         """The distilled candidates of the whole search, strongest first,
         at most ``limit``: per DM and acceleration the harmonic levels'
         peaks above ``min_snr``, merged; harmonic distillation within
         each acceleration trial, acceleration distillation across them;
-        then DM and (whole-number) harmonic distillation across DMs."""
+        then DM and (whole-number) harmonic distillation across DMs.
+        Every row's device work is dispatched before any is fetched."""
         n, tsamp = self.size, self.obs["tsamp"]
         tol, nh = cfg.get("freq_tol", 1e-4), cfg.get("nharmonics", 4)
         max_harm = cfg.get("max_harm", 16)
         bounds = [search_bounds(n, tsamp, k, cfg.get("min_freq", 0.1),
                                 cfg.get("max_freq", 1100.0))
                   for k in range(nh + 1)]
-        thresh = self.npdt(cfg.get("min_snr", 9.0))
-        found = []
+        lims = np.array([b[:2] for b in bounds], np.int32)
+        thresh = np.float32(self.npdt(cfg.get("min_snr", 9.0)))
+        per = max(1, BLOCK_SAMPLES // n)
+        rows = []
         for ii, dm in enumerate(np.asarray(dms, np.float32)):
-            tim_w, mean, std = self.whitened(dm, n)
+            device = self.devices[ii % len(self.devices)]
+            tim_w, mean, std = self.whitened(dm, n, device=device)
+            accs = accel_list(cfg, self.obs, float(dm), n)
+            blocks = []
+            for j in range(0, len(accs), per):
+                shifts, width = self.shifts(accs[j: j + per], device)
+                call = partial(peaks, tim_w, shifts, mean, std, lims, thresh,
+                               nh=nh, width=width, dtype=self.dtype)
+                blocks.append((accs[j: j + per], call,
+                               call(capacity=self.capacity)))
+            rows.append((ii, dm, blocks))
+        found = []
+        for ii, dm, blocks in rows:
             row = []
-            for acc in accel_list(cfg, self.obs, float(dm), n):
-                idx = jnp.asarray(search_index(n, float(acc), tsamp))
-                spec = np.asarray(spectrum(tim_w, idx, mean, std,
-                                           dtype=self.dtype))
-                cands = []
-                for k, lv in enumerate(harmonic_levels(spec, nh, self.npdt)):
-                    start, stop, factor = bounds[k]
-                    hit = np.flatnonzero(lv[start:stop] > thresh) + start
-                    for b, sn in zip(*unique_peaks(hit, lv[hit])):
-                        cands.append(dict(dm=float(dm), dm_idx=ii,
-                                          acc=float(acc), nh=k, snr=sn,
-                                          freq=b * factor))
-                row += distill(cands, "harmonic", tol, max_harm=max_harm)
+            for accs, call, out in blocks:
+                for acc, levels in zip(accs, self._hits(call, out, len(accs),
+                                                        nh + 1, n // 2 + 1)):
+                    cands = []
+                    for k, (hit, snr) in enumerate(levels):
+                        for b, sn in zip(*unique_peaks(hit, snr)):
+                            cands.append(dict(dm=float(dm), dm_idx=ii,
+                                              acc=float(acc), nh=k, snr=sn,
+                                              freq=b * bounds[k][2]))
+                    row += distill(cands, "harmonic", tol, max_harm=max_harm)
             found += distill(row, "accel", tol, tobs=n * tsamp)
         out = distill(found, "dm", tol)
         out = distill(out, "harmonic", tol, max_harm=max_harm,
                       fractional=False)
         return out[: cfg.get("limit", 1000)]
+
+    def _hits(self, call, out, ntrials: int, nlevels: int, nbins: int):
+        """A block's hits, fetched, as (bins, S/N) per trial and level;
+        the block is redone where its hits overflow the capacity."""
+        count = int(out[0])
+        if count > self.capacity:
+            out = call(capacity=1 << (count - 1).bit_length())
+        pos = np.asarray(out[1])[:count]
+        snr = np.asarray(out[2])[:count]
+        # hits come in (trial, level, bin) order: cut them per spectrum
+        cuts = np.searchsorted(pos // nbins, np.arange(ntrials * nlevels + 1))
+        spans = list(zip(cuts[:-1], cuts[1:]))
+        return [[(pos[a:b] % nbins, snr[a:b])
+                 for a, b in spans[t * nlevels: (t + 1) * nlevels]]
+                for t in range(ntrials)]
 
     def folded_snr(self, dm, accel, period) -> float:
         key = (np.float32(dm), np.float32(accel), period)

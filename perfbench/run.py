@@ -18,8 +18,9 @@ the checkout).  Window: CLI calls, one beam each, the beams in turn,
 started while fewer than ``--seconds`` have passed; the call in flight
 when time runs out is completed and counted.  Then the program's
 device state is dropped and the plain reference (``reference.py``)
-searches each beam; every candidate table the window wrote is compared
-with its beam's table (``check.py``).
+searches each beam, its DM rows spread over the cell's chips; every
+candidate table the window wrote is compared with its beam's table
+(``check.py``).
 
 The last stdout line is the result; the compared numbers and their
 limits are the last lines of stderr and the last key of the result.
@@ -252,15 +253,18 @@ def drop_device_state() -> None:
         a.delete()
 
 
-def reference(spec: dict, prep: dict, payload, dtype=None, npdmp: int = 0):
-    """The plain reference over one of the cell's beams and its table
-    (``npdmp``: how many of its candidates it folds itself)."""
+def reference(spec: dict, prep: dict, payload, dtype=None, npdmp: int = 0,
+              devices=None):
+    """The plain reference over one of the cell's beams on ``devices``
+    and its table (``npdmp``: how many of its candidates it folds
+    itself)."""
     from perfbench import check
     from perfbench.reference import Reference
 
     kw = {} if dtype is None else {"dtype": dtype}
     ref = Reference(prep["obs"], payload, float(prep["dms"].max()),
-                    birdies=spec["config"].get("birdies", ()), **kw)
+                    birdies=spec["config"].get("birdies", ()),
+                    devices=devices, **kw)
     return ref, check.as_table(ref, ref.search(prep["dms"], prep["search"]),
                                npdmp)
 
@@ -280,16 +284,19 @@ def correctness(tables: list, ref, ref_table: list, prep: dict,
     return nums
 
 
-def check_beams(spec: dict, prep: dict, tables: list[list], log=None) -> dict:
+def check_beams(spec: dict, prep: dict, tables: list[list], log=None,
+                devices=None) -> dict:
     """The compared numbers over every beam: ``tables[k]`` are the
     tables the window wrote for beam ``k``, each held against that
-    beam's reference; the worst gap, and the tables that missed."""
+    beam's reference on ``devices``; the worst gap, and the tables that
+    missed."""
     out = {"table_gap": 0.0, "snr_gap": 0.0, "fold_gap": 0.0,
            "missed": 0.0, "compared": 0.0}
     for k, (beam, tabs) in enumerate(zip(prep["beams"], tables)):
         if not tabs:
             continue
-        nums = correctness(tabs, *reference(spec, prep, beam["payload"]),
+        nums = correctness(tabs, *reference(spec, prep, beam["payload"],
+                                             devices=devices),
                            prep, log and (lambda m, k=k: log(f"beam {k} {m}")))
         for k in ("table_gap", "snr_gap", "fold_gap"):
             out[k] = max(out[k], nums[k])
@@ -472,7 +479,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT) -> int:
         tables[o["beam"]].append(read_candidates(
             os.path.join(o["outdir"], "overview.xml")))
     t0 = time.perf_counter()
-    nums = check_beams(spec, prep, tables, log)
+    nums = check_beams(spec, prep, tables, log, devices)
     log(f"reference check of {len(done)} tables of {len(beams)} beams, "
         f"{int(nums['compared'])} candidates matched: "
         f"{time.perf_counter() - t0:.3f} s")

@@ -1,7 +1,8 @@
 """A benchmark root at test size: the repository's BENCHMARK.json with
-its configuration cut to a CPU-sized beam (the same channel layout, bit
-depth and search flags, fewer samples and channels), and the harness's
-own traffic mixes (at most two beams each) and metric readers."""
+each configuration cut to a CPU-sized beam by the ``test_size`` object
+in its own file (the same channel layout, bit depth and search flags,
+fewer samples and channels), and the harness's own traffic mixes (at
+most two beams each) and metric readers."""
 
 from __future__ import annotations
 
@@ -12,11 +13,6 @@ import shutil
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-#: config -> the keys changed to make it a CPU-sized beam
-TINY = {
-    "tutorial": dict(nsamps=1 << 15, nchans=32, foff=-2.18, dm_end=100.0),
-}
 
 #: a 2-bit survey-like configuration at test size (the chunked path)
 TWO_BIT = dict(
@@ -29,17 +25,26 @@ TWO_BIT = dict(
             "missed": 0.0})
 
 
+def at_test_size(path: str) -> dict:
+    """The configuration in ``path`` cut to its ``test_size``."""
+    with open(path) as f:
+        cfg = json.load(f)
+    if "test_size" not in cfg:
+        raise ValueError(f"{path} has no \"test_size\": the keys that cut "
+                         "it to a CPU-sized beam")
+    cfg.update(cfg["test_size"])
+    return cfg
+
+
 def make_root(path) -> str:
-    """A root holding BENCHMARK.json, the tiny configurations and a copy
-    of the harness's traffic mixes and metric readers."""
+    """A root holding BENCHMARK.json, the configurations at test size
+    and a copy of the harness's traffic mixes and metric readers."""
     root = str(path)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     os.makedirs(os.path.join(root, "perfbench", "configs"))
     for entry in bench["configs"]:
-        with open(os.path.join(REPO, entry["file"])) as f:
-            cfg = json.load(f)
-        cfg.update(TINY[entry["name"]])
+        cfg = at_test_size(os.path.join(REPO, entry["file"]))
         with open(os.path.join(root, entry["file"]), "w") as f:
             json.dump(cfg, f)
     for sub in ("traffic", "metrics"):
@@ -58,9 +63,10 @@ def make_root(path) -> str:
     return root
 
 
-def add_cell(root: str, name: str, config: dict, traffic: dict) -> None:
-    """Add a configuration, a traffic mix and a cell to ``root`` by
-    writing their files and entries alone."""
+def add_cell(root: str, name: str, config: dict, traffic: dict,
+             chips: int = 1) -> None:
+    """Add a configuration, a traffic mix and a cell on ``chips`` chips
+    to ``root`` by writing their files and entries alone."""
     with open(os.path.join(root, "perfbench", "configs", name + ".json"),
               "w") as f:
         json.dump(config, f)
@@ -74,7 +80,7 @@ def add_cell(root: str, name: str, config: dict, traffic: dict) -> None:
                          "file": f"perfbench/configs/{name}.json",
                          "reduced": config.get("reduced", []), "why": "test"})
     b["workloads"].append({"name": name + ".cell", "config": name,
-                           "traffic": name, "chips": 1, "why": "a test cell"})
+                           "traffic": name, "chips": chips, "why": "a test cell"})
     with open(path, "w") as f:
         json.dump(b, f)
 
