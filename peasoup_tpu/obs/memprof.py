@@ -107,7 +107,16 @@ def memory_analysis_probe(fn, args) -> dict | None:
     try:
         import jax
 
-        compiled = jax.jit(fn).lower(*args).compile()
+        return compiled_footprint(jax.jit(fn).lower(*args).compile())
+    except Exception:
+        return None
+
+
+def compiled_footprint(compiled) -> dict | None:
+    """A compiled executable's ``memory_analysis()`` distilled to plain
+    byte counts, or None when the backend/jax version provides no
+    analysis."""
+    try:
         ma = compiled.memory_analysis()
     except Exception:
         return None

@@ -798,8 +798,93 @@ def build_chunked_search(
     return jax.jit(mapped, donate_argnums=donate)
 
 
+@jax.jit
+def _parts_landed(*parts):
+    """A scalar computed over every flat part: fetching it proves the
+    host-to-device upload of the parts has landed."""
+    return sum(p[-1].astype(jnp.float32) for p in parts)
+
+
+#: the rows sweeps built so far, by shape and mesh (``build_rows_sweep``)
+_ROWS_SWEEPS: dict = {}
+
+
+def build_rows_sweep(mesh: Mesh, *, dm_tile: int, n_parts: int,
+                     nsamps_dev: int, out_nsamps: int, dedisp_method: str,
+                     window_slack: int, time_tile: int, chan_group: int,
+                     max_delay: int):
+    """The jitted dedispersion of a block of delay rows over the
+    replicated flat parts (the fold's and the re-search's rows):
+    ``fn(rows, *parts)``, rows sharded over ``dm``.  Kept by shape and
+    mesh until :func:`release_kept_programs`, so the searches of one
+    geometry share one program."""
+    key = (mesh, dm_tile, n_parts, nsamps_dev, out_nsamps, dedisp_method,
+           window_slack, time_tile, chan_group, max_delay)
+    fn = _ROWS_SWEEPS.get(key)
+    if fn is not None:
+        return fn
+    if dedisp_method == "pallas":
+        from ..ops.dedisperse_pallas import dedisperse_pallas_flat
+
+        def sweep(d, *fs):
+            return dedisperse_pallas_flat(
+                list(fs), d, nsamps_dev, out_nsamps,
+                window_slack=window_slack, dm_tile=dm_tile,
+                time_tile=time_tile, chan_group=chan_group,
+                max_delay=max_delay,
+            )
+    else:
+        def sweep(d, *fs):
+            return dedisperse_flat(list(fs), d, nsamps_dev, out_nsamps)
+    # each device sweeps its block of rows over the replicated parts:
+    # a Mosaic kernel is never partitioned automatically
+    fn = _ROWS_SWEEPS[key] = jax.jit(jax.shard_map(
+        sweep, mesh=mesh,
+        in_specs=(P("dm", None),) + (P(),) * n_parts,
+        out_specs=P("dm", None), check_vma=False))
+    return fn
+
+
+def _loaded_arena_bytes(program, arg_specs) -> int | None:
+    """Temp and code bytes of the executable ``program`` loaded for
+    arguments of ``arg_specs``, from its own memory_analysis (lowering
+    again with the dispatches' shapes reads that executable from the
+    jit's cache and compiles nothing); None where the backend gives no
+    analysis."""
+    from ..obs.memprof import compiled_footprint
+
+    try:
+        fp = compiled_footprint(program.lower(*arg_specs).compile())
+    except Exception:
+        return None
+    return None if fp is None else fp["temp_bytes"] + fp["generated_code_bytes"]
+
+
+def release_kept_programs(keep=None) -> None:
+    """Unload what earlier chunked searches kept loaded, so that no
+    arena a search did not plan for holds HBM beside its own: the chunk
+    program one kept (``MeshPulsarSearch.kept_chunk_program``) and the
+    rows sweeps.  Where that chunk program is ``keep`` (the same
+    geometry again), both stay: ``_chunk_program_fits`` reckoned the
+    fold beside them."""
+    kept = MeshPulsarSearch.kept_chunk_program
+    MeshPulsarSearch.kept_chunk_program = None
+    if kept is not None and kept is keep:
+        return
+    if kept is not None:
+        kept.clear_cache()
+    for fn in _ROWS_SWEEPS.values():
+        fn.clear_cache()
+    _ROWS_SWEEPS.clear()
+
+
 class MeshPulsarSearch(PulsarSearch):
     """Multi-device search: DM trials sharded over a 1-D device mesh."""
+
+    #: the chunk program a chunked search kept loaded for the next
+    #: search of its geometry (``_run_chunked``), process-wide: at most
+    #: one, released by :func:`release_kept_programs`
+    kept_chunk_program = None
 
     def __init__(self, fil, config: SearchConfig, max_devices=None,
                  mesh: Mesh | None = None):
@@ -1459,72 +1544,75 @@ class MeshPulsarSearch(PulsarSearch):
         the COMBINED trial lists (``_trial_lists``)."""
         if getattr(self, "_dev_chunk_static", None) is not None:
             return
-        ndm = len(self.dm_list)
-        ndm_pp = plan["ndm_local_p"] * self.ndev
-        namax_p = plan["namax_p"]
-        accs = np.full((ndm_pp, namax_p), np.nan, np.float32)
-        jerks = np.full((ndm_pp, namax_p), np.nan, np.float32)
-        for i, a in enumerate(acc_lists):
-            accs[i, : len(a)] = a
-            if jerk_lists is not None:
-                jerks[i, : len(a)] = jerk_lists[i]
-        # edge-pad to match the planner's slack bound (padded rows emit
-        # nothing: their accel slots are all NaN)
-        delays = np.empty((ndm_pp, self.fil.nchans), np.int32)
-        delays[:ndm] = self.delays
-        delays[ndm:] = self.delays[-1]
-        nbits = self.fil.header.nbits
-        nchans, nsamps = self.fil.nchans, self.fil.nsamps
-        # single allocation: transpose-copy + killmask + tail pad in
-        # place (three sequential full copies would transiently need
-        # ~3x the multi-GB input on the host).  The transpose itself is
-        # threaded over channel blocks: a byte-granular (nsamps, nchans)
-        # -> (nchans, nsamps) strided assignment is the single largest
-        # host cost of the production prep (numpy releases the GIL in
-        # the strided copy, so threads scale)
-        data = np.zeros(
-            (nchans, max(plan["pad_to"], nsamps)),
-            np.uint8 if nbits <= 8 else np.float32,
-        )
-        src = self.fil.data
-        km = self.killmask
+        # the host prep under one root span: the channel-major layout
+        # as ``Prep-Transpose`` and every upload as ``Prep-Upload``,
+        # each with its ``bytes``
+        with span("Chunk-Prep"):
+            ndm = len(self.dm_list)
+            ndm_pp = plan["ndm_local_p"] * self.ndev
+            namax_p = plan["namax_p"]
+            accs = np.full((ndm_pp, namax_p), np.nan, np.float32)
+            jerks = np.full((ndm_pp, namax_p), np.nan, np.float32)
+            for i, a in enumerate(acc_lists):
+                accs[i, : len(a)] = a
+                if jerk_lists is not None:
+                    jerks[i, : len(a)] = jerk_lists[i]
+            # edge-pad to match the planner's slack bound (padded rows emit
+            # nothing: their accel slots are all NaN)
+            delays = np.empty((ndm_pp, self.fil.nchans), np.int32)
+            delays[:ndm] = self.delays
+            delays[ndm:] = self.delays[-1]
+            nbits = self.fil.header.nbits
+            nchans, nsamps = self.fil.nchans, self.fil.nsamps
+            # single allocation: transpose-copy + killmask + tail pad in
+            # place (three sequential full copies would transiently need
+            # ~3x the multi-GB input on the host).  The transpose itself is
+            # threaded over channel blocks: a byte-granular (nsamps, nchans)
+            # -> (nchans, nsamps) strided assignment is the single largest
+            # host cost of the production prep (numpy releases the GIL in
+            # the strided copy, so threads scale)
+            data = np.zeros(
+                (nchans, max(plan["pad_to"], nsamps)),
+                np.uint8 if nbits <= 8 else np.float32,
+            )
+            src = self.fil.data
+            km = self.killmask
 
-        def _tblock(c0):
-            c1 = min(c0 + 64, nchans)
-            data[c0:c1, :nsamps] = src[:, c0:c1].T
-            if km is not None:
-                data[c0:c1, :nsamps] *= km[c0:c1, None].astype(data.dtype)
+            def _tblock(c0):
+                c1 = min(c0 + 64, nchans)
+                data[c0:c1, :nsamps] = src[:, c0:c1].T
+                if km is not None:
+                    data[c0:c1, :nsamps] *= km[c0:c1, None].astype(data.dtype)
 
-        from concurrent.futures import ThreadPoolExecutor
+            from concurrent.futures import ThreadPoolExecutor
 
-        with ThreadPoolExecutor(min(16, os.cpu_count() or 8)) as ex:
-            list(ex.map(_tblock, range(0, nchans, 64)))
-        rep = NamedSharding(self.mesh, P())
-        uidx, d0_u, pos_u, step_u = self._resample_tables(
-            accs, jerks if jerk_lists is not None else None)
-        self._host_chunk_arrays = (delays, accs, jerks, uidx)
-        parts = tuple(
-            put_global(p, rep)
-            for p in split_flat_channels(
+            with span("Prep-Transpose", bytes=int(data.nbytes)):
+                with ThreadPoolExecutor(min(16, os.cpu_count() or 8)) as ex:
+                    list(ex.map(_tblock, range(0, nchans, 64)))
+            rep = NamedSharding(self.mesh, P())
+            uidx, d0_u, pos_u, step_u = self._resample_tables(
+                accs, jerks if jerk_lists is not None else None)
+            self._host_chunk_arrays = (delays, accs, jerks, uidx)
+            host_parts = split_flat_channels(
                 data,
-                # part_align: sub-band stage 1 needs every part to
-                # hold whole sub-bands (set by _plan_subband_chunks)
+                # part_align: sub-band stage 1 needs every part to hold
+                # whole sub-bands (set by _plan_subband_chunks)
                 align=plan.get(
                     "part_align",
                     2 * plan["chan_group"]
                     if plan["dedisp_method"] == "pallas" else 1),
             )
-        )
-        self._dev_chunk_static = (
-            parts,
-            put_global(d0_u, rep),
-            put_global(pos_u, rep),
-            put_global(step_u, rep),
-            put_global(self.birdies, rep),
-            put_global(self.bwidths, rep),
-        )
+            tables = (d0_u, pos_u, step_u, self.birdies, self.bwidths)
+            nbytes = sum(int(np.asarray(a).nbytes)
+                         for a in (*host_parts, *tables))
+            # the copies start here; what is left of the transfer is
+            # waited for by the first chunk (its ``upload`` phase)
+            with span("Prep-Upload", bytes=nbytes):
+                parts = tuple(put_global(p, rep) for p in host_parts)
+                self._dev_chunk_static = (parts,) + tuple(
+                    put_global(a, rep) for a in tables)
 
-    def _dedisperse_rows_device(self, delays_rows, dm_tile=1):
+    def _dedisperse_rows_device(self, delays_rows, dm_tile=1, min_rows=0):
         """One dedispersion-only dispatch over the resident flat parts
         for the given delay rows (fold re-dedispersion and stage
         measurement).
@@ -1533,16 +1621,21 @@ class MeshPulsarSearch(PulsarSearch):
         block's delay spread is <= the plan's (dm_tile, chan_group)
         bound — and is required when the rows are scattered DMs; the
         stage measurement passes the plan's tile to reflect the real
-        chunk configuration."""
+        chunk configuration.
+
+        The rows are edge-padded to at least ``min_rows`` and to whole
+        dm_tiles on every device, and the padded block is returned (row
+        ``i`` is ``delays_rows[i]``): a caller that passes one
+        ``min_rows`` for any smaller row count gets one program shape."""
         data_parts = self._dev_chunk_static[0]  # flat parts (see
         nchans = self.fil.nchans                # _device_inputs_chunked)
         fn = self._dedisp_rows_program(
             dm_tile, len(data_parts),
             sum(p.shape[0] for p in data_parts) // nchans)
-        # edge-pad the rows to whole dm_tiles on every device
         n = len(delays_rows)
         step = self.ndev * dm_tile
-        rows = np.empty((-(-n // step) * step, nchans), np.int32)
+        rows = np.empty((-(-max(n, min_rows) // step) * step, nchans),
+                        np.int32)
         rows[:n] = delays_rows
         rows[n:] = delays_rows[-1]
         rows_d = put_global(rows, NamedSharding(self.mesh, P("dm", None)))
@@ -1550,42 +1643,21 @@ class MeshPulsarSearch(PulsarSearch):
                   n_rows=int(n),
                   dm_tile=int(dm_tile),
                   gflops=round(self._dedisp_rows_gflops(n), 3)) as sp:
-            return self._maybe_quantise(
-                sp.block(fn(rows_d, *data_parts)[:n]))
+            return self._maybe_quantise(sp.block(fn(rows_d, *data_parts)))
 
     def _dedisp_rows_program(self, dm_tile: int, n_parts: int,
                              nsamps_dev: int):
-        """The jitted rows sweep of :meth:`_dedisperse_rows_device`:
-        (delay rows sharded over ``dm``, *replicated flat parts) ->
-        trials sharded over ``dm``.  One per dm_tile, cached on the
-        search object (the jit cache lives on the callable)."""
-        cache = self.__dict__.setdefault("_dedisp_rows_jit", {})
-        fn = cache.get(dm_tile)
-        if fn is not None:
-            return fn
+        """The jitted rows sweep of :meth:`_dedisperse_rows_device`
+        (:func:`build_rows_sweep` for this search's plan)."""
         plan = self._chunk_plan
-        if plan["dedisp_method"] == "pallas":
-            from ..ops.dedisperse_pallas import dedisperse_pallas_flat
-
-            def sweep(d, *fs):
-                return dedisperse_pallas_flat(
-                    list(fs), d, nsamps_dev, self.out_nsamps,
-                    window_slack=plan["window_slack"],
-                    dm_tile=dm_tile, time_tile=plan["time_tile"],
-                    chan_group=plan["chan_group"],
-                    max_delay=self.max_delay,
-                )
-        else:
-            def sweep(d, *fs):
-                return dedisperse_flat(
-                    list(fs), d, nsamps_dev, self.out_nsamps)
-        # each device sweeps its block of rows over the replicated
-        # parts: a Mosaic kernel is never partitioned automatically
-        fn = cache[dm_tile] = jax.jit(jax.shard_map(
-            sweep, mesh=self.mesh,
-            in_specs=(P("dm", None),) + (P(),) * n_parts,
-            out_specs=P("dm", None), check_vma=False))
-        return fn
+        return build_rows_sweep(
+            self.mesh, dm_tile=int(dm_tile), n_parts=int(n_parts),
+            nsamps_dev=int(nsamps_dev), out_nsamps=int(self.out_nsamps),
+            dedisp_method=plan["dedisp_method"],
+            window_slack=int(plan["window_slack"]),
+            time_tile=int(plan["time_tile"]),
+            chan_group=int(plan["chan_group"]),
+            max_delay=int(self.max_delay))
 
     def _dedisp_rows_gflops(self, n_rows: int) -> float:
         """Modelled Gflops of an ``n_rows``-row dedispersion dispatch
@@ -1624,10 +1696,13 @@ class MeshPulsarSearch(PulsarSearch):
     def _fold_trials_provider(self, dm_idxs):
         """Re-dedisperse just the candidate DM rows for folding (the
         chunked program cannot keep (ndm, out_nsamps) trials resident;
-        the reference holds them host-side, `pipeline_multi.cu:258`)."""
+        the reference holds them host-side, `pipeline_multi.cu:258`).
+        At least ``npdmp`` rows are swept, so the folds of every
+        observation of one geometry share one program shape."""
         uniq = sorted(set(int(i) for i in dm_idxs))
         row_map = {dm: r for r, dm in enumerate(uniq)}
-        trials = self._dedisperse_rows_device(self.delays[uniq])
+        trials = self._dedisperse_rows_device(
+            self.delays[uniq], min_rows=self.config.npdmp)
         return trials, row_map
 
     def _fused_fold_provider(self, dm_idxs):
@@ -1886,6 +1961,8 @@ class MeshPulsarSearch(PulsarSearch):
         # row (possible only if the data changed under the tune key)
         # joins the clipped set for the same re-run path.
         program = build(cap, compact_k)
+        release_kept_programs(keep=program)
+        arg_specs = []  # the shapes of a dispatch's arguments
         todo = []
         n_live = 0  # chunks holding any real (non-padding) DM row
         for ci in range(n_chunks):
@@ -1940,7 +2017,7 @@ class MeshPulsarSearch(PulsarSearch):
                              if live else None),
                       n_trials=n_trials_chunk,
                       gflops=round(gflops, 3)):
-                return program(
+                args = (
                     *data_parts,
                     *sb_args,
                     put_global(delays_h[rows], shard),
@@ -1950,6 +2027,12 @@ class MeshPulsarSearch(PulsarSearch):
                     put_global(uidx_h[rows], shard),
                     d0_u, pos_u, step_u, birdies_d, widths_d,
                 )
+                if not arg_specs:
+                    arg_specs.extend(
+                        jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=a.sharding)
+                        for a in args)
+                return program(*args)
 
         hw_count = 0  # observed high-waters for the tune sidecar
         hw_valid = 0
@@ -1976,10 +2059,7 @@ class MeshPulsarSearch(PulsarSearch):
                 # residual transfer after compile + one chunk's device
                 # time; the multi-GB transfer dominates at production
                 # scale
-                np.asarray(jax.jit(
-                    lambda *ps: sum(p[-1].astype(jnp.float32)
-                                    for p in ps)
-                )(*data_parts))
+                np.asarray(_parts_landed(*data_parts))
                 phases["upload"] = time.time() - tc
                 return out
             tp = time.time()
@@ -2091,25 +2171,39 @@ class MeshPulsarSearch(PulsarSearch):
         ).run(todo)
 
         tp = time.time()
-        # drop OUR per-chunk executables before the re-search / fold
-        # phases: TPU executables reserve their temp arenas at load
-        # time, and the chunk program's (accel_block full-length
-        # spectra, ~3.5 GB at 2^23) plus the resident filterbank left
-        # too little HBM for the later phases (observed
-        # RESOURCE_EXHAUSTED at production scale).  Fine-grained —
-        # unlike the previous process-wide jax.clear_caches(), every
+        # TPU executables reserve their temp arenas at load time, and
+        # the chunk program's (accel_block full-length spectra, ~3.5 GB
+        # at 2^23) plus the resident filterbank once left too little
+        # HBM for the later phases (observed RESOURCE_EXHAUSTED at
+        # production scale).  The chunk executable is KEPT loaded for
+        # the next search of this geometry only where no row is
+        # re-searched and the fold phase at its full batch fits beside
+        # the arena its own memory_analysis gives
+        # (``_chunk_program_fits``); the fold's budget then leaves that
+        # arena out (``retained_arena_bytes``), and the next search
+        # that loads any other chunk program, or takes the fused path,
+        # unloads it first (``release_kept_programs``).  Otherwise (at
+        # HTRU width on one chip and on four: the full fold batch is
+        # ~10 GB) OUR per-chunk executables are RELEASED here, before
+        # the re-search and fold phases, and the next search reads
+        # them again from the compile cache.  Fine-grained — every
         # other compiled program (fold, whiten, tutorial-scale paths)
         # survives.  clear_cache() on the jit object itself: the local
         # `program` / `dispatch` closure still hold the callable, so
         # dropping only the lru entry would leave the executable (and
         # its arena) alive.  (Program caches keyed on Mesh are safe
         # across equal meshes: jax interns Mesh instances.)
-        import gc
+        arena = (None if all_clipped or not arg_specs
+                 else _loaded_arena_bytes(program, arg_specs))
+        if arena is not None and self._chunk_program_fits(arena):
+            MeshPulsarSearch.kept_chunk_program = program
+            self.retained_arena_bytes = arena
+        else:
+            import gc
 
-        if todo:  # `program` is only bound when any chunk was searched
             program.clear_cache()
-        build_chunked_search.cache_clear()
-        gc.collect()
+            build_chunked_search.cache_clear()
+            gc.collect()
         # cleanup (cache drop + full-heap gc, ~1 s on a big host heap)
         # is charged to its own phase: billing it to "research" made
         # clip-free runs look like they paid a re-search
@@ -2175,6 +2269,23 @@ class MeshPulsarSearch(PulsarSearch):
         if ckpt:
             ckpt.remove()
         return result
+
+    def _chunk_program_fits(self, arena: int) -> bool:
+        """True where the fold phase at its full batch (``npdmp``
+        candidates) fits beside the filterbank, a loaded chunk program's
+        ``arena`` bytes and the fold's trial rows, with the 2 GB reserve
+        ``_finalise`` keeps (the same budget and coefficients as
+        ``_finalise`` and ``fold_candidates``)."""
+        from ..search.pipeline import fold_bytes_per_sample
+        from ..search.plan import prev_power_of_two
+
+        cfg = self.config
+        rows = -(-max(cfg.npdmp, 1) // self.ndev) * self.ndev
+        fold = (cfg.npdmp * fold_bytes_per_sample()
+                * prev_power_of_two(self.out_nsamps))
+        need = (self._data_bytes() + arena
+                + 4 * rows * self.out_nsamps + (2 << 30) + fold)
+        return need <= int(cfg.hbm_budget_gb * 1e9)
 
     def _decode_packed(self, packed, ndm_local, namax, nlevels, cap,
                        compact_k):
@@ -2478,6 +2589,9 @@ class MeshPulsarSearch(PulsarSearch):
                 plan, acc_lists, namax, timers, t_total, ckpt,
                 ckpt_done, jerk_lists,
             )
+        # the fused program plans for no arena an earlier chunked
+        # search kept loaded
+        release_kept_programs()
         if cfg.subband_dedisp != "never":
             warn_event(
                 "path_fallback",
