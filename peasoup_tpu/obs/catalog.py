@@ -137,6 +137,11 @@ CATALOG: dict[str, str] = {
                               "control",
     "trace.listener_errors": "span listeners dropped after raising",
     "trace.spans_dropped": "spans dropped past the retention cap",
+    # -- upload (chunked path) ----------------------------------------------
+    "upload.link_bytes": "bytes the chunked path's static upload handed "
+                         "to the host link, summed over devices",
+    "upload.parts_gathered": "flat filterbank parts sent as shards and "
+                             "all-gathered on the devices",
 }
 
 #: metric families whose names are built dynamically (f-strings) —

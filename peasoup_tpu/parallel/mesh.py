@@ -805,6 +805,48 @@ def _parts_landed(*parts):
     return sum(p[-1].astype(jnp.float32) for p in parts)
 
 
+#: the programs that gather sharded flat parts to replicated, by mesh
+#: and part shapes (``put_flat_parts``); an all-gather holds no arena,
+#: so they stay loaded for the process
+_PART_GATHERS: dict = {}
+
+
+def put_flat_parts(mesh: Mesh, host_parts) -> tuple[tuple, int, int]:
+    """Put the flat filterbank parts on every device of ``mesh``,
+    replicated as ``NamedSharding(mesh, P())``.
+
+    A part whose length divides by the device count crosses the host
+    link once: each device takes its contiguous slice (``P("dm")``)
+    and one program all-gathers every such part over the interconnect.
+    Any other part, and every part on one device, is put replicated,
+    a whole copy to each device.  Returns the device parts, how many
+    were gathered, and the bytes handed to the host link summed over
+    devices."""
+    ndev = mesh.devices.size
+    rep = NamedSharding(mesh, P())
+    parts = [None] * len(host_parts)
+    shards = {}
+    link_bytes = 0
+    for i, p in enumerate(host_parts):
+        if ndev > 1 and p.shape[0] % ndev == 0:
+            shards[i] = put_global(p, NamedSharding(mesh, P("dm")))
+            link_bytes += p.nbytes
+        else:
+            parts[i] = put_global(p, rep)
+            link_bytes += p.nbytes * ndev
+    if shards:
+        key = (mesh, tuple((s.shape, s.dtype) for s in shards.values()))
+        gather = _PART_GATHERS.get(key)
+        if gather is None:
+            gather = _PART_GATHERS[key] = jax.jit(
+                lambda *xs: xs, out_shardings=rep)
+        # the shards are dropped on return: each device frees its slice
+        # once the gather that reads it has run
+        for i, g in zip(shards, gather(*shards.values())):
+            parts[i] = g
+    return tuple(parts), len(shards), link_bytes
+
+
 #: the rows sweeps built so far, by shape and mesh (``build_rows_sweep``)
 _ROWS_SWEEPS: dict = {}
 
@@ -1603,14 +1645,19 @@ class MeshPulsarSearch(PulsarSearch):
                     if plan["dedisp_method"] == "pallas" else 1),
             )
             tables = (d0_u, pos_u, step_u, self.birdies, self.bwidths)
-            nbytes = sum(int(np.asarray(a).nbytes)
-                         for a in (*host_parts, *tables))
+            table_bytes = sum(int(np.asarray(a).nbytes) for a in tables)
+            nbytes = sum(int(p.nbytes) for p in host_parts) + table_bytes
             # the copies start here; what is left of the transfer is
             # waited for by the first chunk (its ``upload`` phase)
-            with span("Prep-Upload", bytes=nbytes):
-                parts = tuple(put_global(p, rep) for p in host_parts)
+            with span("Prep-Upload", bytes=nbytes) as sp:
+                parts, gathered, link_bytes = put_flat_parts(
+                    self.mesh, host_parts)
                 self._dev_chunk_static = (parts,) + tuple(
                     put_global(a, rep) for a in tables)
+                link_bytes += table_bytes * self.ndev
+                sp.set(gathered=gathered, link_bytes=link_bytes)
+            METRICS.inc("upload.parts_gathered", gathered)
+            METRICS.inc("upload.link_bytes", link_bytes)
 
     def _dedisperse_rows_device(self, delays_rows, dm_tile=1, min_rows=0):
         """One dedispersion-only dispatch over the resident flat parts
@@ -2059,7 +2106,8 @@ class MeshPulsarSearch(PulsarSearch):
                 # residual transfer after compile + one chunk's device
                 # time; the multi-GB transfer dominates at production
                 # scale
-                np.asarray(_parts_landed(*data_parts))
+                with span("Upload-Wait"):
+                    np.asarray(_parts_landed(*data_parts))
                 phases["upload"] = time.time() - tc
                 return out
             tp = time.time()

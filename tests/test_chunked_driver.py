@@ -1,7 +1,8 @@
 """The chunked driver at a 2-bit survey-like test size on four virtual
-devices: the host-prep spans in the CLI's ``trace.json``, no compile in
-a second search of one geometry, and what a search keeps loaded for the
-next: the chunk program only where the HBM reckoning fits the fold
+devices: the host-prep spans in the CLI's ``trace.json``, the flat
+filterbank parts sent as shards and gathered on the devices, no compile
+in a second search of one geometry, and what a search keeps loaded for
+the next: the chunk program only where the HBM reckoning fits the fold
 beside its arena (which the fold's budget then leaves out), released
 before any other chunk program or the fused path loads."""
 
@@ -11,10 +12,12 @@ import os
 
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from peasoup_tpu.io import read_filterbank
 from peasoup_tpu.obs.injection import synthesize
 from peasoup_tpu.obs.metrics import REGISTRY
+from peasoup_tpu.obs.trace import get_tracer
 from peasoup_tpu.parallel import mesh
 from peasoup_tpu.search import pipeline
 from peasoup_tpu.search.plan import SearchConfig
@@ -53,13 +56,13 @@ def _compiles() -> int:
     return int(REGISTRY.snapshot()["counters"].get("jit.backend_compiles", 0))
 
 
-def _search(path: str, **over) -> mesh.MeshPulsarSearch:
-    """A chunked search on four devices: a CPU-sized beam fits the
+def _search(path: str, ndev: int = 4, **over) -> mesh.MeshPulsarSearch:
+    """A chunked search on ``ndev`` devices: a CPU-sized beam fits the
     fused path, so the DM chunks are given (two rows a device)."""
     cfg = SearchConfig(dm_list=list(DMS), dm_chunk=2,
                        **dict(SEARCH, **over))
     return mesh.MeshPulsarSearch(read_filterbank(path), cfg,
-                                 mesh=mesh.make_mesh(4))
+                                 mesh=mesh.make_mesh(ndev))
 
 
 @pytest.fixture
@@ -94,7 +97,9 @@ def _cli(tmp_path, fil: str, out: str) -> tuple[str, int]:
 
 def test_chunked_prep_spans_nest_with_bytes(tmp_path, cli_chunks):
     """``Chunk-Prep`` is a root span holding ``Prep-Transpose`` and
-    ``Prep-Upload``, each with the bytes it laid out or sent."""
+    ``Prep-Upload``, each with the bytes it laid out or sent; the
+    upload hands the parts to the host link once, not once a device,
+    and the first chunk's wait for it is an ``Upload-Wait`` span."""
     from perfbench import spans
 
     outdir, _ = _cli(tmp_path, _beam(tmp_path, SEEDS[0]), "obs")
@@ -108,6 +113,73 @@ def test_chunked_prep_spans_nest_with_bytes(tmp_path, cli_chunks):
         (child,) = [s for s in ss if s["name"] == name]
         assert by_id[child["args"]["parent_id"]] is prep
         assert child["args"]["bytes"] >= least
+    (up,) = [s for s in ss if s["name"] == "Prep-Upload"]
+    assert up["args"]["gathered"] >= 1
+    assert up["args"]["bytes"] <= up["args"]["link_bytes"] < 4 * least
+    (wait,) = [s for s in ss if s["name"] == "Upload-Wait"]
+    assert wait["s"] >= 0
+
+
+@pytest.mark.parametrize("ndev", [4, 1])
+def test_flat_parts_land_replicated_byte_for_byte(tmp_path, monkeypatch,
+                                                   ndev):
+    """After the chunked prep every flat part is replicated over the
+    mesh and holds the host part byte for byte.  On four devices each
+    part crosses the host link once, as shards gathered on the devices:
+    ``Prep-Upload``'s ``link_bytes`` counts the parts once and the
+    tables once a device.  One device gathers nothing and takes no
+    gather program."""
+    host = []
+    real = mesh.split_flat_channels
+
+    def spy(*a, **kw):
+        out = real(*a, **kw)
+        host.extend(np.array(p) for p in out)
+        return out
+
+    monkeypatch.setattr(mesh, "split_flat_channels", spy)
+    gathers = len(mesh._PART_GATHERS)
+    get_tracer().reset()
+    s = _search(_beam(tmp_path, SEEDS[0]), ndev=ndev)
+    s.run()
+    (up,) = [r for r in get_tracer().records() if r.name == "Prep-Upload"]
+    parts = s._dev_chunk_static[0]
+    assert len(parts) == len(host) >= 1
+    for d, h in zip(parts, host):
+        assert d.sharding == NamedSharding(s.mesh, P())
+        assert d.sharding.is_fully_replicated
+        assert np.asarray(d).tobytes() == h.tobytes()
+    part_bytes = sum(h.nbytes for h in host)
+    table_bytes = up.attrs["bytes"] - part_bytes
+    assert table_bytes > 0
+    assert up.attrs["link_bytes"] == part_bytes + ndev * table_bytes
+    assert up.attrs["gathered"] == (len(host) if ndev > 1 else 0)
+    if ndev == 1:
+        assert len(mesh._PART_GATHERS) == gathers
+
+
+@pytest.mark.parametrize("ndev,lengths,gathered", [
+    (4, [4096, 8192], 2),
+    (4, [4097], 0),  # an uneven part is put whole to each device
+    (4, [4096, 4098], 1),
+    (1, [4096], 0),
+], ids=["even", "uneven", "mixed", "one_device"])
+def test_parts_cross_the_link_by_mesh_and_length(ndev, lengths, gathered):
+    """A part is sent as shards and gathered only where the mesh has
+    more than one device and its length divides by their count; the
+    rest are put replicated, and the link carries them once a device."""
+    m = mesh.make_mesh(ndev)
+    host = [(np.arange(n) % 251).astype(np.uint8) for n in lengths]
+    n0 = len(mesh._PART_GATHERS)
+    parts, got, link = mesh.put_flat_parts(m, host)
+    assert got == gathered
+    for d, h in zip(parts, host):
+        assert d.sharding == NamedSharding(m, P())
+        assert np.asarray(d).tobytes() == h.tobytes()
+    even = [n for n in lengths if ndev > 1 and n % ndev == 0]
+    assert link == sum(even) + ndev * (sum(lengths) - sum(even))
+    if not gathered:
+        assert len(mesh._PART_GATHERS) == n0
 
 
 @pytest.mark.parametrize("second", ["same_beam", "other_beam"])
